@@ -32,6 +32,9 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(os.path.dirname(__file__), "out"))
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (api_bench, compaction_bench, fig1_prefix_skew, fig7_pmss,
                    fig8_ycsb, fig9_ycsb_mixed, fig11_space, fig13_unique_rate,
                    fig14_models, fig15_cnode, fig16_subtrie, kernel_bench,

@@ -1,16 +1,16 @@
-"""Distributed LITS on 8 (simulated) devices through the StringIndex facade:
-CDF range partitioning + all_to_all query routing (DESIGN.md §5, §8).
+"""Distributed LITS through the StringIndex facade: one key-range shard per
+device + all_to_all query routing (DESIGN.md §5, §8).
 
 `DistributedStringIndex` is the mesh implementation of the same typed
 batched-op surface as the local `StringIndex` — construction owns the
-shard build, device placement, and the routed shard_map service.
+shard build, device placement, and the routed shard_map service.  The shard
+count is the number of devices JAX sees: 4 on a 2x2 TPU v5e host, or as many
+CPU devices as XLA is told to fake:
 
     PYTHONPATH=src python examples/distributed_index.py
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python examples/distributed_index.py
 """
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import time
 
 import jax
@@ -25,11 +25,12 @@ def main() -> None:
     rng = np.random.default_rng(0)
     keys = sorted(set(random_strings(rng, 50000, 4, 24)))
     vals = np.arange(len(keys), dtype=np.int64)
-    print(f"{len(keys)} keys -> 8 CDF-range shards")
-    index = DistributedStringIndex.build(keys, vals, n_shards=8,
-                                         per_dest_capacity=512)
+    n = len(jax.devices())
+    print(f"{len(keys)} keys -> {n} key-range shards on {jax.devices()}")
+    index = DistributedStringIndex.build(keys, vals, n_shards=n,
+                                         per_dest_capacity=2048)
 
-    Q = 8 * 2048
+    Q = n * 2048
     qkeys = [keys[i] for i in rng.integers(0, len(keys), Q)]
     found, got = index.get_batch(qkeys)           # compile + warm
     t0 = time.perf_counter()

@@ -301,11 +301,11 @@ class IndexRuntimeConfig:
         )
 
     def validate(self) -> "IndexRuntimeConfig":
-        # alias sets mirror tensor_index.SEARCH_BACKENDS and
-        # kernels.ops._interpret_default exactly
-        if self.search_backend not in ("jnp", "pallas"):
-            raise ValueError(f"search_backend {self.search_backend!r}")
-        if self.kernel_mode not in ("auto", "interpret", "cpu",
-                                    "native", "mosaic", "tpu"):
-            raise ValueError(f"kernel_mode {self.kernel_mode!r}")
+        from repro.core.tensor_index import resolve_search_backend
+        from repro.kernels.ops import resolve_interpret
+
+        # the same resolvers the query paths use: unknown names raise
+        # ValueError, interpret mode on a TPU backend raises RuntimeError
+        resolve_search_backend(self.search_backend)
+        resolve_interpret(self.kernel_mode)
         return self

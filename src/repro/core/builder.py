@@ -383,21 +383,29 @@ class LITSBuilder:
         self.mn_alpha.append(alpha)
         self.mn_beta.append(beta)
         self.mn_nkeys.append(n)
-        # group consecutive equal positions (pos is non-decreasing: CDF monotone)
-        cut = np.flatnonzero(np.diff(pos)) + 1
+        # cut the sorted keys into blocks whose position ranges do not
+        # overlap.  GetCDF is monotone in exact arithmetic, but float32
+        # rounding can put a key one slot BELOW its predecessor; such keys
+        # share one block, and every slot of the block's range holds the
+        # same child item (an alias), so each key is found at its own
+        # position and slot order stays key order (see _set_item,
+        # iter_subtree).  With monotone positions a block is one slot.
+        pos = pos.astype(np.int64)
+        pmax = np.maximum.accumulate(pos)
+        smin = np.minimum.accumulate(pos[::-1])[::-1]
+        cut = np.flatnonzero(pmax[:-1] < smin[1:]) + 1
         starts = np.concatenate([[0], cut])
         ends = np.concatenate([cut, [n]])
         for s, e in zip(starts, ends):
-            p = int(pos[s])
             sub = eids[s:e]
             if e - s == 1:
-                self.items.data[base + p] = make_item(TAG_ENTRY, int(sub[0]))
+                child = make_item(TAG_ENTRY, int(sub[0]))
             elif (e - s) > self.cfg.heavy_slot_frac * n or (e - s) == n:
-                self.items.data[base + p] = self._build_trie(
-                    sub, bytes_mat[s:e], lens[s:e]
-                )
+                child = self._build_trie(sub, bytes_mat[s:e], lens[s:e])
             else:
-                self.items.data[base + p] = self._build_group(sub, bytes_mat[s:e], lens[s:e])
+                child = self._build_group(sub, bytes_mat[s:e], lens[s:e])
+            self.items.data[base + int(pos[s:e].min()):
+                            base + int(pmax[e - 1]) + 1] = child
         return make_item(TAG_MNODE, nid)
 
     def _build_cnode(self, eids: np.ndarray, bytes_mat: np.ndarray, lens: np.ndarray) -> int:
@@ -554,8 +562,77 @@ class LITSBuilder:
                 loc = base + m - 1
             else:
                 pos = self._node_pos(nid, q, qlen, pl, m)
+                lo, hi = self._order_span(base, m, pos, key)
+                if lo < hi:
+                    eid = self._merge_insert(base, lo, hi, q, qlen, val)
+                    return True, path, base + lo, eid
                 loc = base + pos
             item = int(self.items.data[loc])
+
+    def _edge_key(self, item: int, last: bool) -> Optional[bytes]:
+        """Smallest (``last=False``) or largest key under ``item``; None
+        when the subtree holds no key."""
+        while True:
+            tag, p = item_tag(item), item_payload(item)
+            if tag == TAG_EMPTY:
+                return None
+            if tag == TAG_ENTRY:
+                return self.key_at(p)
+            if tag == TAG_CNODE:  # h-pointers are kept in key order
+                j = int(self.cn_base.data[p]) + (
+                    int(self.cn_cnt.data[p]) - 1 if last else 0)
+                return self.key_at(int(self.ch_ent.data[j]))
+            if tag == TAG_TRIE:
+                item = int((self.tr_right if last else self.tr_left).data[p])
+                continue
+            base, m = int(self.mn_slot_base.data[p]), int(self.mn_slot_cnt.data[p])
+            nz = np.flatnonzero(self.items.data[base : base + m])
+            if nz.size == 0:
+                return None
+            item = int(self.items.data[base + nz[-1 if last else 0]])
+
+    def _order_span(self, base: int, m: int, pos: int,
+                    key: bytes) -> Tuple[int, int]:
+        """Slot range an insert of ``key`` at slot ``pos`` must merge into
+        one aliased block (see _build_mnode) to keep slot order = key
+        order: the blocks left of ``pos`` holding larger keys and right of
+        it holding smaller ones, which a float32 position dip can produce.
+        ``(pos, pos)`` when the order holds."""
+        slots = self.items.data[base : base + m]
+        own = int(slots[pos])
+        lo = hi = pos
+        for step in (-1, 1):
+            j = pos + step
+            while 0 <= j < m:
+                it = int(slots[j])
+                edge = None if it in (0, own) else \
+                    self._edge_key(it, last=step < 0)  # skip EMPTY, aliases
+                if edge is not None:
+                    if edge < key if step < 0 else edge > key:
+                        break
+                    lo, hi = min(lo, j), max(hi, j)
+                j += step
+        if (lo, hi) != (pos, pos):  # widen to whole alias blocks at both ends
+            while lo > 0 and slots[lo - 1] == slots[lo] != 0:
+                lo -= 1
+            while hi < m - 1 and slots[hi + 1] == slots[hi] != 0:
+                hi += 1
+        return lo, hi
+
+    def _merge_insert(self, base: int, lo: int, hi: int, q, qlen, val) -> int:
+        """Insert a new key together with every key in slots ``lo..hi``:
+        one child built from their union, aliased over the whole range."""
+        eid = self._add_entry_bytes(q, qlen, val)
+        eids, seen = [eid], set()
+        for j in range(lo, hi + 1):
+            it = int(self.items.data[base + j])
+            if it and it not in seen:
+                seen.add(it)
+                eids.extend(self.iter_subtree(it))
+        eids.sort(key=self.key_at)
+        child = self._build_group(np.asarray(eids, np.int64))
+        self.items.data[base + lo : base + hi + 1] = child
+        return eid
 
     def insert(self, key: bytes, val: int) -> bool:
         inserted, path, _loc, eid = self._insert_walk(key, val)
@@ -640,7 +717,17 @@ class LITSBuilder:
             else:
                 self.tr_right.data[tid] = item
         else:
+            old = self.items.data[loc]
             self.items.data[loc] = item
+            if item_tag(old) != TAG_EMPTY:
+                # the slots of one block alias one child (_build_mnode): a
+                # non-empty child has one parent, so equal neighbours are
+                # exactly its aliases
+                for step in (-1, 1):
+                    j = loc + step
+                    while 0 <= j < self.items.n and self.items.data[j] == old:
+                        self.items.data[j] = item
+                        j += step
 
     def _rebuild_at(self, loc, item: int) -> None:
         eids = np.array(list(self.iter_subtree(item)), np.int64)
@@ -964,8 +1051,12 @@ class LITSBuilder:
             return
         nid = item_payload(item)
         base, m = int(self.mn_slot_base.data[nid]), int(self.mn_slot_cnt.data[nid])
+        prev = None
         for p in range(m):
-            yield from self.iter_subtree(int(self.items.data[base + p]))
+            it = int(self.items.data[base + p])
+            if it != prev:      # aliased slots of one block: visit once
+                yield from self.iter_subtree(it)
+            prev = it
 
     def scan(self, begin: bytes, count: int) -> List[Tuple[bytes, int]]:
         """Host range scan: first ``count`` entries with key >= begin."""

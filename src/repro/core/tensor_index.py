@@ -541,12 +541,18 @@ def _delta_sort_order_impl(db_bytes, de_off, de_len, de_count,
         | w[:, :, 3]
     unclaimed = (jnp.arange(dcap, dtype=jnp.int32)
                  >= de_count).astype(jnp.int32)
-    # jnp.lexsort: LAST key is primary — claimed entries first, then the
-    # most-significant packed word downwards, length tie-break last
+    # LSD radix order: one stable argsort per key, least significant first
+    # (length tie-break, then the packed words from the last upwards, then
+    # claimed entries first) — the permutation jnp.lexsort gives, but one
+    # multi-operand TPU sort of all these keys takes minutes to compile at
+    # a 16k-entry delta, and a chain of single-key sorts takes seconds
     keys = (de_len,) + tuple(
         packed[:, i] for i in range(packed.shape[1] - 1, -1, -1)
     ) + (unclaimed,)
-    return jnp.lexsort(keys).astype(jnp.int32)
+    perm = jnp.arange(dcap, dtype=jnp.int32)
+    for k in keys:
+        perm = perm[jnp.argsort(k[perm], stable=True)]
+    return perm
 
 
 @partial(jax.jit, static_argnames=("width",))
@@ -578,13 +584,11 @@ def _mutate_batch(ti: TensorIndex, kbytes: jax.Array, klens: jax.Array,
     # update base values in-place (functional); deletes never touch values —
     # they shadow via the delta buffer so merge_delta can reconcile them
     do_base = bfound & ~is_del
-    upd_idx = jnp.where(do_base, beid, 0)
-    ent_val_lo = ti.ent_val_lo.at[upd_idx].set(
-        jnp.where(do_base, val_lo, jnp.take(ti.ent_val_lo, upd_idx)), mode="drop"
-    )
-    ent_val_hi = ti.ent_val_hi.at[upd_idx].set(
-        jnp.where(do_base, val_hi, jnp.take(ti.ent_val_hi, upd_idx)), mode="drop"
-    )
+    # ops that update no base entry scatter out of range (dropped): a
+    # rewrite of entry 0 with its old value would race a real update of it
+    upd_idx = jnp.where(do_base, beid, ti.ent_val_lo.shape[0])
+    ent_val_lo = ti.ent_val_lo.at[upd_idx].set(val_lo, mode="drop")
+    ent_val_hi = ti.ent_val_hi.at[upd_idx].set(val_hi, mode="drop")
     qh = _hash32(kbytes, klens)
     hcap = ti.dh_slot.shape[0]
     dcap = ti.de_off.shape[0]

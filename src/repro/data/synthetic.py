@@ -9,6 +9,7 @@ GPKL is reached.
 """
 from __future__ import annotations
 
+import zlib
 from typing import Callable, Dict, List
 
 import numpy as np
@@ -34,13 +35,13 @@ def gen_email(rng, n: int) -> List[bytes]:
     first = _words(rng, 400, 3, 8)
     last = _words(rng, 600, 4, 9)
     dom = [b"gmail.com", b"yahoo.com", b"hotmail.com", b"example.org", b"mail.net"]
-    out = set()
+    out = {}
     while len(out) < n:
         k = b"%s.%s%02d@%s" % (
             first[rng.integers(0, len(first))], last[rng.integers(0, len(last))],
             rng.integers(0, 100), dom[rng.integers(0, len(dom))],
         )
-        out.add(k)
+        out[k] = None
     return list(out)
 
 
@@ -48,31 +49,31 @@ def gen_idcard(rng, n: int) -> List[bytes]:
     """18-byte Chinese id-card: 6B region + 8B yyyymmdd + 4B unique code."""
     regions = [b"%06d" % r for r in rng.choice(
         np.arange(110000, 659000), size=200, replace=False)]
-    out = set()
+    out = {}
     while len(out) < n:
         region = regions[rng.integers(0, len(regions))]
         y, m, d = rng.integers(1950, 2010), rng.integers(1, 13), rng.integers(1, 29)
         code = b"%04d" % rng.integers(0, 10000)
-        out.add(region + b"%04d%02d%02d" % (y, m, d) + code)
+        out[region + b"%04d%02d%02d" % (y, m, d) + code] = None
     return list(out)
 
 
 def gen_phone(rng, n: int) -> List[bytes]:
     """Faker-style phone numbers, 11-23B."""
-    out = set()
+    out = {}
     fmts = [b"+1-%03d-%03d-%04d", b"0%02d-%04d-%04d", b"(%03d) %03d-%04d", b"+86 %03d %04d %04d"]
     while len(out) < n:
         f = fmts[rng.integers(0, len(fmts))]
-        out.add(f % (rng.integers(0, 1000), rng.integers(0, 10000) % 1000
-                     if f != fmts[1] else rng.integers(0, 10000), rng.integers(0, 10000)))
+        out[f % (rng.integers(0, 1000), rng.integers(0, 10000) % 1000
+                     if f != fmts[1] else rng.integers(0, 10000), rng.integers(0, 10000))] = None
     return list(out)
 
 
 def gen_rands(rng, n: int, lo=2, hi=61) -> List[bytes]:
     """Uniform a-z random strings (paper: 2-61B)."""
-    out = set()
+    out = {}
     while len(out) < n:
-        out.add(_choice_str(rng, _LOWER, rng.integers(lo, hi + 1)))
+        out[_choice_str(rng, _LOWER, rng.integers(lo, hi + 1))] = None
     return list(out)
 
 
@@ -83,20 +84,20 @@ def gen_url(rng, n: int) -> List[bytes]:
     tld = [b".com", b".org", b".net", b".de", b".io"]
     hosts = [b"www." + w + tld[rng.integers(0, len(tld))] for w in _words(rng, max(n // 50, 10), 5, 14)]
     paths = _words(rng, 500, 3, 10)
-    out = set()
+    out = {}
     while len(out) < n:
         h = hosts[min(int(rng.zipf(1.3)) - 1, len(hosts) - 1)]
         depth = rng.integers(1, 6)
         p = b"/".join(paths[rng.integers(0, len(paths))] for _ in range(depth))
         suffix = b"%d.html" % rng.integers(0, 10000)
-        out.add(b"http://" + h + b"/" + p + b"/" + suffix)
+        out[b"http://" + h + b"/" + p + b"/" + suffix] = None
     return list(out)
 
 
 def gen_wiki(rng, n: int) -> List[bytes]:
     """Wiki titles: Capitalized_words_with_underscores (avg ~15B)."""
     vocab = _words(rng, 4000, 3, 10)
-    out = set()
+    out = {}
     while len(out) < n:
         k = rng.integers(1, 4)
         words = [vocab[min(int(rng.zipf(1.4)) - 1, len(vocab) - 1)] for _ in range(k)]
@@ -104,7 +105,7 @@ def gen_wiki(rng, n: int) -> List[bytes]:
         t = b"_".join(words)
         if rng.random() < 0.2:
             t += b"_(%d)" % rng.integers(1900, 2024)
-        out.add(t)
+        out[t] = None
     return list(out)
 
 
@@ -113,12 +114,12 @@ def gen_address(rng, n: int) -> List[bytes]:
     streets = _words(rng, 800, 4, 10)
     cities = _words(rng, 60, 4, 10)
     sfx = [b" st", b" ave", b" rd", b" blvd"]
-    out = set()
+    out = {}
     while len(out) < n:
-        out.add(b"%d %s%s %s" % (
+        out[b"%d %s%s %s" % (
             rng.integers(1, 9999), streets[rng.integers(0, len(streets))],
             sfx[rng.integers(0, len(sfx))], cities[min(int(rng.zipf(1.5)) - 1, len(cities) - 1)],
-        ))
+        )] = None
     return list(out)
 
 
@@ -126,21 +127,21 @@ def gen_names(rng, n: int) -> List[bytes]:
     """imdb/geoname-like proper names (avg ~13B)."""
     first = _words(rng, 1200, 3, 9)
     last = _words(rng, 3000, 4, 11)
-    out = set()
+    out = {}
     while len(out) < n:
         f = bytes(first[min(int(rng.zipf(1.3)) - 1, len(first) - 1)]).capitalize()
         l = bytes(last[rng.integers(0, len(last))]).capitalize()
         k = f + b" " + l
         if k in out:
             k += b" %s" % _choice_str(rng, _LOWER, 2).capitalize()
-        out.add(k)
+        out[k] = None
     return list(out)
 
 
 def gen_reddit(rng, n: int) -> List[bytes]:
     """reddit usernames: short, moderately skewed prefixes (avg ~11B)."""
     vocab = _words(rng, 2000, 3, 8)
-    out = set()
+    out = {}
     while len(out) < n:
         w = bytes(vocab[min(int(rng.zipf(1.4)) - 1, len(vocab) - 1)])
         style = rng.integers(0, 4)
@@ -152,7 +153,7 @@ def gen_reddit(rng, n: int) -> List[bytes]:
             k = b"xX" + w + b"Xx"
         else:
             k = w
-        out.add(k)
+        out[k] = None
     return list(out)
 
 
@@ -161,12 +162,12 @@ def gen_dblp(rng, n: int) -> List[bytes]:
     lead = [b"a survey of ", b"towards ", b"on the ", b"learning ", b"efficient ",
             b"a study of ", b"deep ", b"scalable "]
     vocab = _words(rng, 3000, 3, 11)
-    out = set()
+    out = {}
     while len(out) < n:
         k = lead[min(int(rng.zipf(1.2)) - 1, len(lead) - 1)]
         nw = rng.integers(6, 14)
         k += b" ".join(bytes(vocab[min(int(rng.zipf(1.3)) - 1, len(vocab) - 1)]) for _ in range(nw))
-        out.add(k[:255])
+        out[k[:255]] = None
     return list(out)
 
 
@@ -186,7 +187,10 @@ DATASETS: Dict[str, Callable] = {
 
 
 def load(name: str, n: int, seed: int = 0) -> List[bytes]:
-    rng = np.random.default_rng((hash(name) & 0xFFFF, seed))
+    # crc32, not hash(): str and bytes hashes are salted per process, so
+    # hash(name) -- or the order of a set of keys -- would differ in every
+    # run.  The generators collect keys in dicts (insertion-ordered) too.
+    rng = np.random.default_rng((zlib.crc32(name.encode()) & 0xFFFF, seed))
     return DATASETS[name](rng, n)
 
 

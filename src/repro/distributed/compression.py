@@ -41,7 +41,6 @@ def compress_tree(grads):
 def make_compressed_dp_step(model, opt_cfg, mesh, axis: str = "data"):
     """Pure-DP train step: grads int8-compressed + psum'd inside shard_map."""
     from repro.train import optimizer as opt_mod
-    from jax.experimental.shard_map import shard_map
 
     n = 1
     for a, s in zip(mesh.axis_names, mesh.devices.shape):
@@ -73,11 +72,11 @@ def make_compressed_dp_step(model, opt_cfg, mesh, axis: str = "data"):
 
     pspec = P()          # params replicated (pure DP)
     bspec = P(axis)      # batch sharded
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(pspec, pspec, pspec, bspec),
         out_specs=(pspec, pspec, pspec, pspec),
-        check_rep=False,
+        check_vma=False,
     ))
 
 

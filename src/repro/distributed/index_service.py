@@ -1,25 +1,24 @@
-"""Distributed LITS query service: CDF range partition + all_to_all routing.
+"""Distributed LITS query service: key-range partition + all_to_all routing.
 
-The paper's own global model is the partition function: ``GetCDF`` is monotone
-non-decreasing w.r.t. lexicographic order (tested property, DESIGN.md §5), so
-CDF boundary values define a correct range partition of the key space.  Each
-shard holds an independent LITS over its key range; all shards' pools are
-padded to a common size and stacked with a leading shard axis, so the whole
-service is one pytree sharded over the ``data`` mesh axis.
+The sorted keys are cut into ``n_shards`` equal contiguous ranges (DESIGN.md
+§5).  Each shard holds an independent LITS over its key range, all built on
+one global HPT; all shards' pools are padded to a common size and stacked
+with a leading shard axis, so the whole service is one pytree sharded over
+the ``data`` mesh axis.
 
 Query path (one ``shard_map`` program, this is the collective pattern a
 1000-node deployment runs):
 
-  1. every device computes GetCDF of its resident queries (HPT replicated),
-  2. bucketizes against the global boundaries -> owner shard,
-  3. ``all_to_all`` scatters queries to owners (fixed per-destination
+  1. every device compares its resident queries with the first key of each
+     shard (``split keys``, replicated) -> owner shard,
+  2. ``all_to_all`` scatters queries to owners (fixed per-destination
      capacity, overflow reported),
-  4. owners run the local jitted LITS search,
-  5. ``all_to_all`` returns (found, value) results to the askers.
+  3. owners run the local jitted LITS search,
+  4. ``all_to_all`` returns (found, value) results to the askers.
 
-Float ties at a boundary are covered by an ε-margin recheck: a not-found
-whose CDF lies within ε of the boundary is retried on the neighbour shard
-(second pass), preserving exactness.
+Routing compares bytes, so it is exact: a float32 CDF computed on the device
+can round differently from the host's, and a key next to a CDF boundary would
+then be sent to the wrong shard.
 """
 from __future__ import annotations
 
@@ -31,15 +30,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import LITSBuilder, StringSet, freeze, lookup_values
-from repro.core.hpt import get_cdf_impl
 from repro.core.strings import sort_order
 from repro.core.tensor_index import (
     STATIC_FIELDS, TensorIndex, base_search_impl, pad_queries,
     resolve_search_backend, scan_batch,
 )
 from repro.index import StringIndexBase
-
-BOUNDARY_EPS = 1e-6
+from repro.kernels.strops import str_cmp_full
 
 
 class RoutingOverflowError(RuntimeError):
@@ -49,7 +46,8 @@ class RoutingOverflowError(RuntimeError):
 @dataclasses.dataclass
 class ShardedIndex:
     stacked: TensorIndex          # every leaf has a leading [n_shards] dim
-    boundaries: np.ndarray        # (n_shards-1,) f32 CDF split points
+    split_bytes: np.ndarray       # (n_shards-1, width) u8: first key of
+    split_lens: np.ndarray        # shards 1.. (zero-padded) and its length
     n_shards: int
     width: int
 
@@ -57,35 +55,31 @@ class ShardedIndex:
 def build_sharded(keys: List[bytes], values: np.ndarray, n_shards: int,
                   **builder_kw) -> ShardedIndex:
     ss = StringSet.from_list(keys)
+    n = len(ss)
+    if n < n_shards:
+        raise ValueError(f"{n} keys cannot fill {n_shards} shards")
     order = sort_order(ss)
     ss = ss.take(order)
     values = np.asarray(values)[order]
-    # one global HPT (trained on everything) shared by all shards = the router
+    # one global HPT (trained on everything) shared by all shards: pools are
+    # stacked, so every shard must index the same CDF table
     probe = LITSBuilder(**builder_kw)
     probe.bulkload(StringSet(ss.bytes.copy(), ss.lens.copy()), values.copy())
     hpt = probe.hpt
     width = probe.width
-    from repro.core.hpt import get_cdf_np64
-
-    cdfs = get_cdf_np64(hpt, ss).astype(np.float32)
-    n = len(ss)
-    cuts = [int(round(i * n / n_shards)) for i in range(1, n_shards)]
-    boundaries = []
-    for c in cuts:
-        lo = cdfs[c - 1] if c > 0 else 0.0
-        hi = cdfs[c] if c < n else 1.0
-        boundaries.append((float(lo) + float(hi)) / 2.0)
-    boundaries = np.asarray(boundaries, np.float32)
-    shard_of = np.searchsorted(boundaries, cdfs, side="right")
+    cuts = [int(round(i * n / n_shards)) for i in range(n_shards + 1)]
     tis = []
     for s in range(n_shards):
-        m = shard_of == s
+        m = slice(cuts[s], cuts[s + 1])
         b = LITSBuilder(hpt=hpt, **{k: v for k, v in builder_kw.items() if k != "hpt"})
         sub = StringSet(ss.bytes[m], ss.lens[m])
         b.bulkload(sub, values[m], width=width)
         tis.append(freeze(b))
     stacked = _stack_indices(tis)
-    return ShardedIndex(stacked, boundaries, n_shards, width)
+    split_bytes = np.zeros((n_shards - 1, width), np.uint8)
+    split_bytes[:, : ss.width] = ss.bytes[cuts[1:-1], :width]
+    return ShardedIndex(stacked, split_bytes, ss.lens[cuts[1:-1]].copy(),
+                        n_shards, width)
 
 
 def _stack_indices(tis: List[TensorIndex]) -> TensorIndex:
@@ -145,7 +139,6 @@ def make_service_fn(sidx: ShardedIndex, mesh, axis: str = "data",
     (DESIGN.md §7); ``None`` resolves from ``REPRO_SEARCH_BACKEND``.
     ``interpret`` overrides the Pallas execution mode (None -> env).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     shard_axes = (axis,) if shard_axes is None else tuple(shard_axes)
@@ -154,14 +147,20 @@ def make_service_fn(sidx: ShardedIndex, mesh, axis: str = "data",
     n = sidx.n_shards
     C = per_dest_capacity
     W = sidx.width
-    boundaries = jnp.asarray(sidx.boundaries)
+    split_pool = jnp.asarray(sidx.split_bytes.reshape(-1))
+    split_lens = [int(x) for x in sidx.split_lens]
 
     def local(stk: TensorIndex, qbytes, qlens):
         # stk leaves carry a leading [1] local shard dim
         ti = _slice_shard(stk, 0)
         Q = qbytes.shape[0]
-        cdf = get_cdf_impl(ti.cdf_tab, ti.prob_tab, qbytes, qlens, 0)
-        owner = jnp.searchsorted(boundaries, cdf, side="right").astype(jnp.int32)
+        # owner = number of shards whose first key is <= the query
+        owner = jnp.zeros(Q, jnp.int32)
+        for j, ln in enumerate(split_lens):
+            c = str_cmp_full(qbytes, qlens, split_pool,
+                             jnp.full(Q, j * W, jnp.int32),
+                             jnp.full(Q, ln, jnp.int32))
+            owner += (c >= 0).astype(jnp.int32)
         # pack queries into per-destination buffers of capacity C
         order = jnp.argsort(owner)
         so, sq, sl = owner[order], qbytes[order], qlens[order]
@@ -195,11 +194,11 @@ def make_service_fn(sidx: ShardedIndex, mesh, axis: str = "data",
         return gather_f[inv], gather_lo[inv], gather_hi[inv], overflow[None]
 
     qspec = P(shard_axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), qspec, qspec),
         out_specs=(qspec, qspec, qspec, qspec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -219,12 +218,12 @@ class DistributedStringIndex(StringIndexBase):
     report ``Status.UNSUPPORTED`` — rebuild via :meth:`build` to ingest.
     SCANs are served (:meth:`scan_entries`): each shard runs the same
     delta-aware ``scan_batch`` engine as the local index (with an empty
-    delta this reduces to the frozen order), and because the CDF partition
-    is a range partition of lexicographic order (DESIGN.md §5), per-shard
-    windows concatenate in shard order into the global window.  Front it
-    with :class:`repro.serve.service.IndexService`
-    (DESIGN.md §9) to serve it as an async multi-tenant request plane —
-    the service treats both implementations identically.
+    delta this reduces to the frozen order), and because shards are
+    contiguous key ranges (DESIGN.md §5), per-shard windows concatenate
+    in shard order into the global window.  Front it with
+    :class:`repro.serve.service.IndexService` (DESIGN.md §9) to serve it
+    as an async multi-tenant request plane — the service treats both
+    implementations identically.
 
     Construction places every stacked pool over the mesh partition axis
     (``NamedSharding(mesh, P(axis))``), so callers no longer hand-roll the
@@ -263,10 +262,12 @@ class DistributedStringIndex(StringIndexBase):
     @classmethod
     def build(cls, keys: List[bytes], values: np.ndarray, n_shards: int,
               mesh=None, **kw) -> "DistributedStringIndex":
-        """Bulk load: CDF-range partition -> per-shard LITS -> mesh placement."""
+        """Bulk load: key-range partition -> per-shard LITS -> mesh placement."""
         sidx = build_sharded(keys, values, n_shards)
         if mesh is None:
-            mesh = jax.make_mesh((n_shards,), ("data",))
+            from repro.launch.mesh import make_mesh
+
+            mesh = make_mesh((n_shards,), ("data",))
         return cls(sidx, mesh, **kw)
 
     @property
@@ -339,9 +340,8 @@ class DistributedStringIndex(StringIndexBase):
         shard-side GET path, serving scans skip the delta region — a
         hand-built stacked index carrying unmerged delta entries must not
         scan keys that shard-side GETs cannot see (and whose bytes live
-        outside the cached base-pool mirrors).  The CDF partition is a
-        range partition of lexicographic order (§5: ``GetCDF`` is
-        monotone), so shard ``s``'s window sorts entirely before shard
+        outside the cached base-pool mirrors).  Shards are contiguous key
+        ranges (§5), so shard ``s``'s window sorts entirely before shard
         ``s+1``'s — per-shard windows concatenate in shard order and the
         first ``window`` survivors are the global answer.  Shards whose
         range ends below a query return empty windows and drop out; a

@@ -5,11 +5,13 @@ real TPU backend they compile natively.  ``interpret`` is resolved ONCE per
 process (cached) from the default backend, overridable without code edits via
 the ``REPRO_KERNEL_BACKEND`` environment variable:
 
-* ``REPRO_KERNEL_BACKEND=interpret`` — force interpreter mode (CPU containers,
-  debugging on TPU),
+* ``REPRO_KERNEL_BACKEND=interpret`` — force interpreter mode (CPU only),
 * ``REPRO_KERNEL_BACKEND=native``    — force native Mosaic compilation,
 * unset / ``auto``                   — interpret unless ``jax.default_backend()``
   is ``tpu``.
+
+Interpret mode is refused on a TPU backend (``RuntimeError``), however it is
+asked for: a kernel must never run in the interpreter on the chip unnoticed.
 
 Tests that need to flip the mode mid-process call
 ``_interpret_default.cache_clear()`` after changing the env var.
@@ -32,11 +34,25 @@ from .traverse import fused_search_pallas
 KERNEL_BACKENDS = ("auto", "interpret", "native")
 
 
+def _checked(interpret: bool) -> bool:
+    """Pass ``interpret`` through; refuse the interpreter on a TPU backend."""
+    if interpret and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "Pallas interpret mode was requested on a TPU backend; kernels "
+            "compile natively there (unset REPRO_KERNEL_BACKEND / "
+            "kernel_backend, or use 'native')")
+    return interpret
+
+
+def _resolve(interpret: bool | None) -> bool:
+    return _checked(_interpret_default() if interpret is None else interpret)
+
+
 @functools.lru_cache(maxsize=1)
 def _interpret_default() -> bool:
     mode = os.environ.get("REPRO_KERNEL_BACKEND", "auto").strip().lower()
     if mode in ("interpret", "cpu"):
-        return True
+        return _checked(True)
     if mode in ("native", "mosaic", "tpu"):
         return False
     if mode not in ("", "auto"):
@@ -59,7 +75,7 @@ def resolve_interpret(mode: str | None = None) -> bool:
     if m in ("", "auto"):
         return _interpret_default()
     if m in ("interpret", "cpu"):
-        return True
+        return _checked(True)
     if m in ("native", "mosaic", "tpu"):
         return False
     raise ValueError(
@@ -74,7 +90,7 @@ def hpt_cdf(qbytes, qlens, start=0, *, cdf_tab, prob_tab, variant: str = "gather
     return hpt_cdf_pallas(
         qbytes, jnp.asarray(qlens, jnp.int32), start, cdf_tab, prob_tab,
         block_b=block_b, max_steps=max_steps, variant=variant,
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=_resolve(interpret),
     )
 
 
@@ -87,7 +103,7 @@ def hpt_locate(qbytes, qlens, start, alpha, beta, nslots, *, cdf_tab, prob_tab,
         qbytes, bc(qlens, jnp.int32), bc(start, jnp.int32), bc(alpha, jnp.float32),
         bc(beta, jnp.float32), bc(nslots, jnp.int32), cdf_tab, prob_tab,
         block_b=block_b, max_steps=max_steps,
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=_resolve(interpret),
     )
 
 
@@ -96,7 +112,7 @@ def cnode_probe(hashes, qhash, cnt, frm=None, *, block_b: int = 512,
     """First matching h-pointer slot per query (or -1)."""
     return cnode_probe_pallas(
         hashes, qhash, cnt, frm, block_b=block_b,
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=_resolve(interpret),
     )
 
 
@@ -111,7 +127,7 @@ def fused_rank(ti, qbytes, qlens, *, block_b: int = 256,
     return fused_rank_pallas(
         qbytes, jnp.asarray(qlens, jnp.int32), ti.ent_sorted, ti.ent_off,
         ti.ent_len, ti.key_bytes, rank_iters=ti.rank_iters, block_b=block_b,
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=_resolve(interpret),
     )
 
 
@@ -134,7 +150,7 @@ def fused_scan(ti, qbytes, qlens, *, window: int, block_b: int = 256,
         ti.ent_off, ti.ent_len, ti.key_bytes, ti.de_count, ti.ds_order,
         ti.de_off, ti.de_len, ti.db_bytes, ti.de_tomb,
         window=window, rank_iters=ti.rank_iters, block_b=block_b,
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=_resolve(interpret),
     )
 
 
@@ -154,5 +170,5 @@ def fused_search(ti, qbytes, qlens, *, block_b: int = 256,
         ti.key_bytes, ti.ent_off, ti.ent_len, ti.cdf_tab, ti.prob_tab,
         width=ti.width, max_iters=ti.max_iters, cnode_cap=ti.cnode_cap,
         cdf_steps=ti.cdf_steps, block_b=block_b,
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=_resolve(interpret),
     )

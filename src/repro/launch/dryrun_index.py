@@ -4,7 +4,7 @@ os.environ["XLA_FLAGS"] = os.environ.get(
 )
 """Dry-run for the distributed LITS query service on the production mesh.
 
-Topology: the index is CDF-range-partitioned 16 ways over ``data`` and
+Topology: the index is key-range-partitioned 16 ways over ``data`` and
 replicated across ``model`` (and ``pod``): each model column is a full
 serving replica; queries are row-sharded over every mesh axis.  One step =
 route (all_to_all over data) -> local LITS search -> return (all_to_all).

@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.configs.base import IndexRuntimeConfig
 from repro.configs.registry import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LMModel
 from repro.serve.engine import ServeEngine
 
@@ -35,6 +36,7 @@ def main() -> None:
                          "the index DELETE path")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -438,7 +438,6 @@ def moe_apply(
             top_k=top_k, capacity_factor=capacity_factor, act=act)
         return out.reshape(B, S, d)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     r = rules()
@@ -489,7 +488,7 @@ def moe_apply(
     else:
         wi_spec = P("model", fsdp if fsdp else None, None)
         wo_spec = P("model", None, fsdp if fsdp else None)
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(
             bspec,
@@ -497,6 +496,6 @@ def moe_apply(
             wi_spec, wi_spec, wo_spec,
         ),
         out_specs=bspec,
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["wi0"], wi1, p["wo"])
     return out

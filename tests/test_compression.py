@@ -54,7 +54,9 @@ def test_compressed_dp_step_single_device():
     m = LMModel(r)
     params = m.init(jax.random.PRNGKey(0))
     opt_cfg = AdamWConfig(lr=1e-3, state_dtype=jnp.float32, warmup_steps=1, total_steps=50)
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
     step = make_compressed_dp_step(m, opt_cfg, mesh)
     opt_state = init_state(params, opt_cfg)
     err = init_error_state(params)

@@ -25,7 +25,8 @@ rng = np.random.default_rng(5)
 keys = sorted(set(random_strings(rng, 4000, 3, 24)))
 vals = np.arange(len(keys), dtype=np.int64) * 11 + 5
 sidx = build_sharded(keys, vals, n_shards=8)
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 
 # spread the stacked index over the mesh (leading shard axis -> 'data')
 from jax.sharding import NamedSharding, PartitionSpec as P

@@ -87,7 +87,8 @@ from repro.launch import steps as steps_mod
 from repro.models import LMModel
 from repro.train.optimizer import AdamWConfig
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 set_mesh(mesh)
 cfg = dataclasses.replace(ARCHS["chatglm3-6b"].reduced(), tp=2, n_kv_heads=2, n_heads=4)
 shape = ShapeSpec("smoke", 64, 8, "train")

@@ -104,7 +104,9 @@ def test_elastic_reshard(setup):
 
     m, pipe, opt = setup
     params = m.init(jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree_util.tree_map(lambda _: NamedSharding(mesh, P()), params)
     moved = reshard(params, sh)
     for a, b in zip(_leaves(params), _leaves(moved)):

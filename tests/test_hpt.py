@@ -32,7 +32,7 @@ def test_tables_are_distributions(trained):
 @given(st.lists(key_st, min_size=2, max_size=16))
 @settings(max_examples=150, deadline=None)
 def test_cdf_monotone_in_key_order(trained, keys):
-    """The property that makes the CDF range-partitioner correct (DESIGN §5)."""
+    """GetCDF is monotone in key order in exact arithmetic (DESIGN §5)."""
     keys = sorted(set(keys))
     ss = StringSet.from_list(keys, width=24)
     v = get_cdf_np64(trained, ss)

@@ -303,6 +303,32 @@ def test_interpret_default_cached(monkeypatch):
         ops._interpret_default.cache_clear()
 
 
+def test_interpret_refused_on_tpu_backend(monkeypatch):
+    """On a TPU backend the Pallas interpreter is refused however it is
+    requested: env var, explicit kernel backend, launcher config, or a raw
+    ``interpret=True`` handed to a kernel wrapper."""
+    from repro.configs.base import IndexRuntimeConfig
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    ops._interpret_default.cache_clear()
+    try:
+        monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
+        assert ops._interpret_default() is False
+        assert ops.resolve_interpret("native") is False
+        with pytest.raises(RuntimeError, match="interpret"):
+            ops.resolve_interpret("interpret")
+        with pytest.raises(RuntimeError, match="interpret"):
+            ops._resolve(True)
+        with pytest.raises(RuntimeError, match="interpret"):
+            IndexRuntimeConfig(kernel_mode="interpret").validate()
+        ops._interpret_default.cache_clear()
+        monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
+        with pytest.raises(RuntimeError, match="interpret"):
+            ops._interpret_default()
+    finally:
+        ops._interpret_default.cache_clear()
+
+
 def test_env_selected_pallas_end_to_end(rng, monkeypatch):
     """REPRO_SEARCH_BACKEND=pallas drives the whole search path."""
     keys = sorted(set(random_strings(rng, 200, 2, 12)))

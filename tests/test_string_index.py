@@ -219,6 +219,19 @@ def test_snapshot_version_and_format_errors(rng, tmp_path):
         StringIndex.load(str(not_snap))
 
 
+def test_update_of_first_entry_survives_a_mixed_batch():
+    """A base-value update of entry 0 is kept when other ops in the same
+    batch update no base entry (they must not rewrite entry 0)."""
+    keys = [b"a%03d" % i for i in range(100)]
+    index = StringIndex.bulk_load(keys, np.arange(100, dtype=np.int64))
+    res = index.execute([PutRequest(b"a000", 777), PutRequest(b"zzz", 5),
+                         DeleteRequest(b"a007"), PutRequest(b"a050", 888)])
+    assert [r.updated for r in res.results] == [True, False, False, True]
+    found, vals = index.get_batch([b"a000", b"a050", b"zzz", b"a007"])
+    assert found.tolist() == [True, True, True, False]
+    assert vals[:3].tolist() == [777, 888, 5]
+
+
 def test_config_beats_env(rng, monkeypatch):
     """Config precedence: explicit field > env var > default (DESIGN.md §8)."""
     monkeypatch.setenv("REPRO_SEARCH_BACKEND", "pallas")
