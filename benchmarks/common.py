@@ -125,7 +125,3 @@ def host_insert_kops(structure: str, loaded: List[bytes], to_insert: List[bytes]
         b.insert(k, i)
     dt = time.perf_counter() - t0
     return len(to_insert) / dt / 1e3
-
-
-def csv_row(name: str, us_per_call: float, derived: str = "") -> str:
-    return f"{name},{us_per_call:.4f},{derived}"

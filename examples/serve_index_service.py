@@ -114,8 +114,10 @@ def main() -> None:
           f"completed={s.completed} flushes={s.flushes} "
           f"coalescing={s.coalescing_factor:.1f} ops/dispatch "
           f"max_flush={s.max_flush}")
-    print(f"latency p50={s.p50_ms:.2f}ms p99={s.p99_ms:.2f}ms; "
-          f"shed={s.shed} maintenance_merges={s.merges} "
+    print(f"latency p50={s.p50_ms:.2f}ms p99={s.p99_ms:.2f}ms "
+          f"(queue wait {s.mean_queue_wait_ms:.2f}ms + flush "
+          f"{s.mean_flush_ms:.2f}ms, {s.syncs_per_flush:.2f} device syncs "
+          f"per flush); shed={s.shed} maintenance_merges={s.merges} "
           f"delta_fill={s.delta_fill:.2f}")
     print(f"errors={len(errors)}")
     assert not errors, errors[:3]

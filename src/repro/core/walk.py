@@ -233,8 +233,8 @@ def scan_merged(
     def frozen_only():
         # EMPTY delta: the merge degenerates to the frozen stream — one
         # contiguous window gather (the legacy scan), no merge loop and no
-        # delta rank.  This is what keeps zero-fill scans at parity with
-        # the frozen-only engine (BENCH_scan.json acceptance row).
+        # delta rank, so zero-fill scans cost what the frozen-only engine
+        # did.
         idx = bi[:, None] + cols
         valid = idx < n_base[:, None]
         eids = jnp.take(ent_sorted, jnp.minimum(idx, n_arr - 1))

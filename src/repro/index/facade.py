@@ -45,6 +45,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import LITSBuilder, LITSConfig, StringSet
 from repro.core.tensor_index import (
@@ -268,6 +269,9 @@ class StringIndexBase:
     """
 
     config: IndexConfig
+    # device->host syncs on the request path (one per get, put, delete or
+    # scan group); backends that do not count them read 0
+    host_syncs: int = 0
 
     def execute(self, batch: Sequence[Request]) -> BatchResult:
         raise NotImplementedError
@@ -301,6 +305,7 @@ class StringIndex(StringIndexBase):
         self._backend = config.resolved_search_backend()
         self._interpret = config.resolved_interpret()
         self.merge_count = 0
+        self.host_syncs = 0
         self._host_pool = None         # lazy (key_bytes, ent_off, ent_len) copies
         # None = no merge in flight; a list = the epoch-merge journal: every
         # mutation applied between begin_merge() and commit_merge() is
@@ -397,15 +402,20 @@ class StringIndex(StringIndexBase):
             return np.zeros(0, bool), np.zeros(0, np.int64)
         import jax
 
-        qb, ql = pad_queries(list(keys), self.ti.width)
-        found, eid, isd = search_batch(
-            self.ti, jnp.asarray(qb), jnp.asarray(ql),
-            backend=self._backend, interpret=self._interpret)
-        lo, hi = lookup_values(self.ti, eid, isd)
+        with TraceAnnotation("lits.index.get.encode"):
+            qb, ql = pad_queries(list(keys), self.ti.width)
+            qb, ql = jnp.asarray(qb), jnp.asarray(ql)
+        with TraceAnnotation("lits.index.get.dispatch"):
+            found, eid, isd = search_batch(
+                self.ti, qb, ql, backend=self._backend,
+                interpret=self._interpret)
+            lo, hi = lookup_values(self.ti, eid, isd)
         # ONE host sync for the whole get group
-        found, lo, hi = jax.device_get((found, lo, hi))
-        vals = _join_values(lo, hi)
-        return found, np.where(found, vals, 0)
+        with TraceAnnotation("lits.index.get.sync"):
+            found, lo, hi = jax.device_get((found, lo, hi))
+        self.host_syncs += 1
+        with TraceAnnotation("lits.index.get.decode"):
+            return found, np.where(found, _join_values(lo, hi), 0)
 
     def put_batch(self, keys: Sequence[bytes],
                   values: Sequence[int]) -> Tuple[np.ndarray, np.ndarray, bool]:
@@ -419,25 +429,28 @@ class StringIndex(StringIndexBase):
             return np.zeros(0, bool), np.zeros(0, bool), False
         import jax
 
-        qb, ql = pad_queries(list(keys), self.ti.width)
-        lo_np, hi_np = _split_np(np.asarray(values, np.int64))
-        self.ti, ins, upd = insert_batch(
-            self.ti, jnp.asarray(qb), jnp.asarray(ql),
-            jnp.asarray(lo_np), jnp.asarray(hi_np))
-        # ONE host sync: op masks + the delta state the merge policy needs
-        ins, upd, de_count, overflow = jax.device_get(
-            (ins, upd, self.ti.de_count, self.ti.delta_overflow))
-        self._delta_fill = float(de_count) / self.ti.de_off.shape[0]
-        self._overflowed = bool(overflow)
-        if self._merge_journal is not None:
-            # epoch merge in flight: journal the ACCEPTED ops (rejected /
-            # over-width ops already reported failure — re-draining them
-            # would resurrect work the caller was told did not happen)
-            acc = ins | upd
-            if acc.any():
-                self._merge_journal.append(
-                    ("put", qb[acc], ql[acc], lo_np[acc], hi_np[acc]))
-        merged = self._maybe_merge(bool(overflow))
+        with TraceAnnotation("lits.index.put"):
+            qb, ql = pad_queries(list(keys), self.ti.width)
+            lo_np, hi_np = _split_np(np.asarray(values, np.int64))
+            self.ti, ins, upd = insert_batch(
+                self.ti, jnp.asarray(qb), jnp.asarray(ql),
+                jnp.asarray(lo_np), jnp.asarray(hi_np))
+            # ONE host sync: op masks + the delta state the merge policy needs
+            with TraceAnnotation("lits.index.put.sync"):
+                ins, upd, de_count, overflow = jax.device_get(
+                    (ins, upd, self.ti.de_count, self.ti.delta_overflow))
+            self.host_syncs += 1
+            self._delta_fill = float(de_count) / self.ti.de_off.shape[0]
+            self._overflowed = bool(overflow)
+            if self._merge_journal is not None:
+                # epoch merge in flight: journal the ACCEPTED ops (rejected /
+                # over-width ops already reported failure — re-draining them
+                # would resurrect work the caller was told did not happen)
+                acc = ins | upd
+                if acc.any():
+                    self._merge_journal.append(
+                        ("put", qb[acc], ql[acc], lo_np[acc], hi_np[acc]))
+            merged = self._maybe_merge(bool(overflow))
         return ins, upd, merged
 
     def delete_batch(self, keys: Sequence[bytes]) -> Tuple[np.ndarray, np.ndarray, bool]:
@@ -454,20 +467,24 @@ class StringIndex(StringIndexBase):
             return np.zeros(0, bool), np.zeros(0, bool), False
         import jax
 
-        qb, ql = pad_queries(list(keys), self.ti.width)
-        self.ti, deleted, rejected = delete_batch(
-            self.ti, jnp.asarray(qb), jnp.asarray(ql))
-        # ONE host sync: op masks + the delta state the merge policy needs
-        deleted, rejected, de_count, overflow = jax.device_get(
-            (deleted, rejected, self.ti.de_count, self.ti.delta_overflow))
-        self._delta_fill = float(de_count) / self.ti.de_off.shape[0]
-        self._overflowed = bool(overflow)
-        if self._merge_journal is not None and deleted.any():
-            # journal only EFFECTIVE deletes (absent keys are no-ops on the
-            # merged index too; rejected tombstones were reported as data)
-            self._merge_journal.append(
-                ("delete", qb[deleted], ql[deleted], None, None))
-        merged = self._maybe_merge(bool(overflow))
+        with TraceAnnotation("lits.index.delete"):
+            qb, ql = pad_queries(list(keys), self.ti.width)
+            self.ti, deleted, rejected = delete_batch(
+                self.ti, jnp.asarray(qb), jnp.asarray(ql))
+            # ONE host sync: op masks + the delta state the merge policy needs
+            with TraceAnnotation("lits.index.delete.sync"):
+                deleted, rejected, de_count, overflow = jax.device_get(
+                    (deleted, rejected, self.ti.de_count,
+                     self.ti.delta_overflow))
+            self.host_syncs += 1
+            self._delta_fill = float(de_count) / self.ti.de_off.shape[0]
+            self._overflowed = bool(overflow)
+            if self._merge_journal is not None and deleted.any():
+                # journal only EFFECTIVE deletes (absent keys are no-ops on
+                # the merged index too; rejected tombstones were reported)
+                self._merge_journal.append(
+                    ("delete", qb[deleted], ql[deleted], None, None))
+            merged = self._maybe_merge(bool(overflow))
         return deleted, rejected, merged
 
     def scan_batch(self, starts: Sequence[bytes], window: int):
@@ -510,23 +527,34 @@ class StringIndex(StringIndexBase):
         index.  Per-op failures come back as :class:`Status` codes; the
         only exceptions raised are for malformed requests (unknown op
         types).
+
+        In a profiler trace the call is a ``lits.index.execute`` span over
+        ``lits.index.plan`` and one span per op group (``lits.index.get.*``,
+        ``put``, ``delete``, ``scan``), each group's one ``device_get``
+        under its own ``.sync`` span; ``host_syncs`` counts those syncs.
         """
+        with TraceAnnotation("lits.index.execute"):
+            return self._execute(batch)
+
+    def _execute(self, batch: Sequence[Request]) -> BatchResult:
         results: List[Optional[OpResult]] = [None] * len(batch)
         gets: List[Tuple[int, GetRequest]] = []
         puts: List[Tuple[int, PutRequest]] = []
         dels: List[Tuple[int, DeleteRequest]] = []
         scans: List[Tuple[int, ScanRequest]] = []
-        for i, req in enumerate(batch):
-            if isinstance(req, GetRequest):
-                gets.append((i, req))
-            elif isinstance(req, PutRequest):
-                puts.append((i, req))
-            elif isinstance(req, DeleteRequest):
-                dels.append((i, req))
-            elif isinstance(req, ScanRequest):
-                scans.append((i, req))
-            else:
-                raise TypeError(f"unknown request type: {type(req).__name__}")
+        with TraceAnnotation("lits.index.plan"):
+            for i, req in enumerate(batch):
+                if isinstance(req, GetRequest):
+                    gets.append((i, req))
+                elif isinstance(req, PutRequest):
+                    puts.append((i, req))
+                elif isinstance(req, DeleteRequest):
+                    dels.append((i, req))
+                elif isinstance(req, ScanRequest):
+                    scans.append((i, req))
+                else:
+                    raise TypeError(
+                        f"unknown request type: {type(req).__name__}")
 
         merged = False
         width = self.ti.width
@@ -558,7 +586,8 @@ class StringIndex(StringIndexBase):
 
         if gets:
             found, vals = self.get_batch([r.key for _, r in gets])
-            self._map_get_results(gets, found, vals, width, results)
+            with TraceAnnotation("lits.index.get.decode"):
+                self._map_get_results(gets, found, vals, width, results)
 
         if scans:
             import jax
@@ -569,41 +598,46 @@ class StringIndex(StringIndexBase):
                 by_window.setdefault(w, []).append((i, req))
             pool, ent_off, ent_len = self._host_entries()
             for w, group in by_window.items():
-                eids, valid, isd = self.scan_batch([r.start for _, r in group], w)
-                vlo, vhi = lookup_values(self.ti, jnp.maximum(eids, 0), isd)
-                fetch = [eids, valid, isd, vlo, vhi]
-                if self._delta_fill > 0.0:
-                    # delta entries may appear in the window: gather their
-                    # key bytes device-side (the frozen host pool mirror
-                    # cannot serve them), bundled into the same sync
-                    e = jnp.minimum(jnp.maximum(eids, 0),
-                                    self.ti.de_off.shape[0] - 1)
-                    doff = jnp.take(self.ti.de_off, e)
-                    didx = jnp.minimum(
-                        doff[..., None]
-                        + jnp.arange(self.ti.width, dtype=jnp.int32),
-                        self.ti.db_bytes.shape[0] - 1)
-                    fetch += [jnp.take(self.ti.de_len, e),
-                              jnp.take(self.ti.db_bytes, didx)]
-                # ONE host sync per scan group
-                got = jax.device_get(fetch)
-                eids, valid, isd, vlo, vhi = got[:5]
-                dlen, dbytes = got[5:] if len(got) > 5 else (None, None)
-                vals = _join_values(vlo, vhi)
-                for row, (i, req) in enumerate(group):
-                    entries = []
-                    for col, (e, v, ok, d) in enumerate(zip(
-                            eids[row].tolist(), vals[row].tolist(),
-                            valid[row].tolist(), isd[row].tolist())):
-                        if not ok:
-                            continue
-                        if d:
-                            key = dbytes[row, col, : dlen[row, col]].tobytes()
-                        else:
-                            key = pool[ent_off[e]: ent_off[e] + ent_len[e]] \
-                                .tobytes()
-                        entries.append((key, v))
-                    results[i] = OpResult(Status.OK, entries=tuple(entries))
+                with TraceAnnotation("lits.index.scan"):
+                    eids, valid, isd = self.scan_batch(
+                        [r.start for _, r in group], w)
+                    vlo, vhi = lookup_values(
+                        self.ti, jnp.maximum(eids, 0), isd)
+                    fetch = [eids, valid, isd, vlo, vhi]
+                    if self._delta_fill > 0.0:
+                        # delta entries may appear in the window: gather
+                        # their key bytes device-side (the frozen host pool
+                        # mirror cannot serve them), bundled into the sync
+                        e = jnp.minimum(jnp.maximum(eids, 0),
+                                        self.ti.de_off.shape[0] - 1)
+                        doff = jnp.take(self.ti.de_off, e)
+                        didx = jnp.minimum(
+                            doff[..., None]
+                            + jnp.arange(self.ti.width, dtype=jnp.int32),
+                            self.ti.db_bytes.shape[0] - 1)
+                        fetch += [jnp.take(self.ti.de_len, e),
+                                  jnp.take(self.ti.db_bytes, didx)]
+                    # ONE host sync per scan group
+                    with TraceAnnotation("lits.index.scan.sync"):
+                        got = jax.device_get(fetch)
+                    self.host_syncs += 1
+                    eids, valid, isd, vlo, vhi = got[:5]
+                    dlen, dbytes = got[5:] if len(got) > 5 else (None, None)
+                    vals = _join_values(vlo, vhi)
+                    for row, (i, req) in enumerate(group):
+                        entries = []
+                        for col, (e, v, ok, d) in enumerate(zip(
+                                eids[row].tolist(), vals[row].tolist(),
+                                valid[row].tolist(), isd[row].tolist())):
+                            if not ok:
+                                continue
+                            if d:
+                                key = dbytes[row, col, : dlen[row, col]]
+                            else:
+                                key = pool[ent_off[e]: ent_off[e] + ent_len[e]]
+                            entries.append((key.tobytes(), v))
+                        results[i] = OpResult(Status.OK,
+                                              entries=tuple(entries))
 
         return BatchResult(
             results=results,  # type: ignore[arg-type]

@@ -72,6 +72,9 @@ def main() -> None:
     print(f"index-service flushes={sv.flushes} "
           f"coalescing={sv.coalescing_factor:.1f} ops/dispatch "
           f"p50={sv.p50_ms:.2f}ms p99={sv.p99_ms:.2f}ms "
+          f"queue_wait={sv.mean_queue_wait_ms:.2f}ms "
+          f"flush={sv.mean_flush_ms:.2f}ms "
+          f"syncs/flush={sv.syncs_per_flush:.2f} "
           f"shed={sv.shed} maintenance_merges={sv.merges}")
 
 

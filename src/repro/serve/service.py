@@ -39,7 +39,14 @@ engine was built for:
   by write traffic, not index size.  Maintenance failures are counted and
   surfaced (``maintenance_errors``), each distinct error logged once.
 * :meth:`stats` — a :class:`ServiceStats` snapshot: queue depth, flush
-  sizes, coalescing factor, shed count, p50/p99 op latency.
+  sizes, coalescing factor, shed count, p50/p99 op latency, and the
+  cumulative queue wait, flush time and device syncs behind them.
+* Tracing — the flusher marks each loop turn (``lits.service.coalesce``)
+  and each flush (``lits.service.flush`` with its ``flush`` id and
+  ``ops``, over ``lock_wait``, the index's ``lits.index.*`` spans and
+  ``resolve``) with ``jax.profiler.TraceAnnotation``: in a profiler trace
+  they share the device planes' clock; without one they cost about a
+  microsecond each.
 
 The backing index is ANY :class:`~repro.index.StringIndexBase` — the local
 single-device :class:`~repro.index.StringIndex` or the mesh-distributed
@@ -61,6 +68,7 @@ from functools import lru_cache
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.index import (
     DeleteRequest,
@@ -103,7 +111,14 @@ class ServiceConfig:
 
 @dataclasses.dataclass
 class ServiceStats:
-    """Point-in-time service metrics snapshot (one :meth:`IndexService.stats` call)."""
+    """Point-in-time service metrics snapshot (one :meth:`IndexService.stats` call).
+
+    ``p50_ms``/``p99_ms`` are submit->resolve times over the last
+    ``ServiceConfig.latency_window`` submissions.  The ``*_total`` fields
+    and ``host_syncs`` are cumulative since the last ``reset_stats``; the
+    ``mean_*`` and ``syncs_per_flush`` properties divide them by
+    ``completed`` or ``flushes``, and differences of two snapshots give the
+    same over an interval."""
 
     submitted: int = 0             # ops admitted into the queue
     completed: int = 0             # ops resolved through a flush
@@ -116,6 +131,9 @@ class ServiceStats:
     delta_fill: float = 0.0        # backing index delta fill right now
     p50_ms: float = 0.0            # median submit->resolve latency
     p99_ms: float = 0.0
+    queue_wait_ms_total: float = 0.0  # sum over ops of submit -> popped
+    flush_ms_total: float = 0.0    # sum over flushes of popped -> resolved
+    host_syncs: int = 0            # backing index device syncs on requests
     # epoch-based compaction metrics (DESIGN.md §10)
     epoch: int = 0                 # backing index compaction epoch
     merge_pause_ms: float = 0.0    # last commit pause (index lock held)
@@ -126,6 +144,19 @@ class ServiceStats:
     # never silently retried forever
     maintenance_errors: int = 0
     last_maintenance_error: Optional[str] = None
+
+    @property
+    def mean_queue_wait_ms(self) -> float:
+        return self.queue_wait_ms_total / self.completed if self.completed \
+            else 0.0
+
+    @property
+    def mean_flush_ms(self) -> float:
+        return self.flush_ms_total / self.flushes if self.flushes else 0.0
+
+    @property
+    def syncs_per_flush(self) -> float:
+        return self.host_syncs / self.flushes if self.flushes else 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -246,6 +277,10 @@ class IndexService:
         self._merge_pause_ms_max = 0.0
         self._merge_wall_ms = 0.0
         self._redrained = 0
+        self._queue_wait_s = 0.0
+        self._flush_s = 0.0
+        self._host_syncs0 = self._index_syncs()
+        self._flush_seq = 0                     # flusher thread only
         self._maintenance_errors = 0
         self._last_maintenance_error: Optional[str] = None
         self._logged_errors: set = set()
@@ -484,9 +519,8 @@ class IndexService:
         are journaled, and the commit swap re-drains the journal — the only
         request-path pause is that commit (bounded by concurrent write
         traffic, not index size).  ``blocking=True`` forces the legacy
-        stop-the-world path (the merge holds the index lock end to end) —
-        kept for backends without the seams and as the benchmark baseline
-        (``benchmarks/compaction_bench.py``).
+        stop-the-world path (the merge holds the index lock end to end),
+        kept for backends without the seams.
         """
         begin = getattr(self.index, "begin_merge", None)
         if begin is None or blocking:
@@ -542,6 +576,9 @@ class IndexService:
                 max_flush=self._max_flush,
                 coalescing_factor=(self._completed / self._flushes
                                    if self._flushes else 0.0),
+                queue_wait_ms_total=self._queue_wait_s * 1e3,
+                flush_ms_total=self._flush_s * 1e3,
+                host_syncs=self._index_syncs() - self._host_syncs0,
                 merges=self._merges,
                 # host mirrors only — stats polling must NEVER sync the
                 # device (delta_fill_fraction would; the facade mirror is
@@ -568,9 +605,15 @@ class IndexService:
             self._merge_pause_ms = self._merge_pause_ms_max = 0.0
             self._merge_wall_ms = 0.0
             self._redrained = 0
+            self._queue_wait_s = self._flush_s = 0.0
+            self._host_syncs0 = self._index_syncs()
             self._maintenance_errors = 0
             self._last_maintenance_error = None
             self._latencies.clear()
+
+    def _index_syncs(self) -> int:
+        # a host counter on the backing index, never a device read
+        return int(getattr(self.index, "host_syncs", 0))
 
     @property
     def merge_count(self) -> int:
@@ -603,7 +646,7 @@ class IndexService:
         cfg = self.config
         max_delay = cfg.max_delay_ms / 1e3
         while True:
-            with self._cv:
+            with TraceAnnotation("lits.service.coalesce"), self._cv:
                 # idle: block until a submit/flush/close notifies — no
                 # polling, so a quiet service costs nothing
                 while not self._queue and not self._closed:
@@ -623,45 +666,72 @@ class IndexService:
                     self._cv.wait(left)
                 # pop whole groups until the op budget is met (a flush may
                 # overshoot max_batch by at most one group — groups are
-                # atomic so a caller's batch resolves in one piece)
-                items, ops = [], 0
+                # atomic so a caller's batch resolves in one piece).  Queue
+                # wait sums ops * (t_pop - t_submit) in O(1) per group
+                t_pop = time.monotonic()
+                items, ops, t_sum = [], 0, 0.0
                 while self._queue and ops < cfg.max_batch:
                     p = self._queue.popleft()
                     items.append(p)
-                    ops += len(p.reqs)
+                    n = len(p.reqs)
+                    ops += n
+                    t_sum += n * p.t_submit
                 self._queued_ops -= ops
+                self._queue_wait_s += ops * t_pop - t_sum
                 if not self._queue:  # sticky: flush() drains the WHOLE queue
                     self._flush_asap = False
             if items:
-                self._run_flush(items, ops)
+                self._run_flush(items, ops, t_pop)
 
-    def _run_flush(self, items: List[_Pending], n_ops: int) -> None:
+    def _run_flush(self, items: List[_Pending], n_ops: int,
+                   t_pop: float) -> None:
+        self._flush_seq += 1
+        with TraceAnnotation("lits.service.flush", flush=self._flush_seq,
+                             ops=n_ops):
+            try:
+                flat: List[Request] = []
+                for p in items:
+                    flat.extend(p.reqs)
+                with TraceAnnotation("lits.service.lock_wait"):
+                    self._index_lock.acquire()
+                try:
+                    res = self.index.execute(flat)
+                finally:
+                    self._index_lock.release()
+            except BaseException as e:
+                self._fail(items, e)
+                return
+            with TraceAnnotation("lits.service.resolve"):
+                self._resolve(items, res.results, n_ops, t_pop)
+            # let maintenance know the delta may have grown (or overflowed —
+            # byte-pool/probe rejections can need compaction at low fill)
+            thr = self.config.merge_threshold
+            if thr is not None and (
+                    getattr(self.index, "delta_fill", 0.0) >= thr
+                    or getattr(self.index, "delta_overflowed", False)):
+                self._maint_wake.set()
+
+    def _resolve(self, items: List[_Pending], results: List[OpResult],
+                 n_ops: int, t_pop: float) -> None:
         try:
-            flat: List[Request] = []
-            for p in items:
-                flat.extend(p.reqs)
-            with self._index_lock:
-                res = self.index.execute(flat)
-            now = time.monotonic()
             done: List = []
             lo = 0
             for p in items:
-                group = res.results[lo: lo + len(p.reqs)]
+                group = results[lo: lo + len(p.reqs)]
                 lo += len(p.reqs)
                 out = [self._scope_scan(enc.start, r)
                        if type(raw) is ScanRequest else r
                        for enc, raw, r in zip(p.reqs, p.raws, group)]
                 done.append((p, out[0] if p.single else out))
-        except BaseException as e:  # resolve, don't strand the callers
-            for p in items:
-                p.future._set(None, e)
-            with self._done_cv:
-                self._done_cv.notify_all()
+        except BaseException as e:
+            self._fail(items, e)
             return
+        now = time.monotonic()
         with self._cv:
             self._flushes += 1
             self._completed += n_ops
             self._max_flush = max(self._max_flush, n_ops)
+            self._flush_s += now - t_pop
             for p, _ in done:
                 # one sample per submission (a batch waits as one request)
                 self._latencies.append((now - p.t_submit) * 1e3)
@@ -669,13 +739,14 @@ class IndexService:
             p.future._set(r)
         with self._done_cv:     # ONE wakeup for the whole flush
             self._done_cv.notify_all()
-        # let maintenance know the delta may have grown (or overflowed —
-        # byte-pool/probe rejections can need compaction at low fill)
-        thr = self.config.merge_threshold
-        if thr is not None and (
-                getattr(self.index, "delta_fill", 0.0) >= thr
-                or getattr(self.index, "delta_overflowed", False)):
-            self._maint_wake.set()
+
+    def _fail(self, items: List[_Pending], exc: BaseException) -> None:
+        """Resolve every future of a failed flush with its exception, so no
+        caller is stranded."""
+        for p in items:
+            p.future._set(None, exc)
+        with self._done_cv:
+            self._done_cv.notify_all()
 
     def _scope_scan(self, enc_start: bytes, r: OpResult) -> OpResult:
         """Enforce tenant isolation on a scan result: keep only entries under
