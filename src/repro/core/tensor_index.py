@@ -288,9 +288,13 @@ def _payload(item: jax.Array) -> jax.Array:
 # search
 # ---------------------------------------------------------------------------
 
-def _traverse(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array) -> jax.Array:
-    """Tagged-handle walk to terminal items (shared impl: core.walk)."""
-    item, _levels = walk_terminal(
+def _traverse(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array):
+    """Tagged-handle walk to terminal items (shared impl: core.walk).
+
+    Returns ``(item, iters, model_iters)``: the terminal items and the
+    walk's two iteration counts (see :func:`repro.core.walk.walk_terminal`).
+    """
+    item, _levels, iters, model_iters = walk_terminal(
         qbytes, qlens, ti.root_item,
         ti.items, ti.mn_slot_base, ti.mn_slot_cnt, ti.mn_prefix_off,
         ti.mn_prefix_len, ti.mn_alpha, ti.mn_beta,
@@ -298,7 +302,7 @@ def _traverse(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array) -> jax.Array
         ti.key_bytes, ti.cdf_tab, ti.prob_tab,
         width=ti.width, max_iters=ti.max_iters, cdf_steps=ti.cdf_steps,
     )
-    return item
+    return item, iters, model_iters
 
 
 def _resolve_terminal(ti: TensorIndex, qbytes, qlens, item):
@@ -365,36 +369,43 @@ def base_search_impl(ti: TensorIndex, qbytes, qlens, backend: str = "jnp",
     resolved to a concrete value.  Both backends return bit-identical
     ``(found, eid)`` — the contract tested in tests/test_kernels.py.
     ``interpret`` overrides the Pallas execution mode (``None`` -> the
-    cached ``REPRO_KERNEL_BACKEND`` default).
+    cached ``REPRO_KERNEL_BACKEND`` default).  Returns
+    ``(found, eid, iters, model_iters)``: the last two are the walk's
+    iteration counts (:func:`repro.core.walk.walk_terminal`), ``None`` from
+    the fused Pallas kernel, which does not count them.
     """
     if backend == "pallas":
         from repro.kernels import ops as _kops  # lazy: keeps core import light
 
         found, eid, _levels = _kops.fused_search(ti, qbytes, qlens,
                                                  interpret=interpret)
-        return found, eid
-    item = _traverse(ti, qbytes, qlens)
-    return _resolve_terminal(ti, qbytes, qlens, item)
+        return found, eid, None, None
+    item, iters, model_iters = _traverse(ti, qbytes, qlens)
+    found, eid = _resolve_terminal(ti, qbytes, qlens, item)
+    return found, eid, iters, model_iters
 
 
 @partial(jax.jit, static_argnames=("backend", "interpret"))
 def base_search(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
                 backend: str = "jnp", interpret: bool | None = None):
     """Jitted :func:`base_search_impl` (snapshot search, delta skipped)."""
-    return base_search_impl(ti, qbytes, qlens, backend, interpret)
+    return base_search_impl(ti, qbytes, qlens, backend, interpret)[:2]
 
 
 @partial(jax.jit, static_argnames=("backend", "interpret"))
 def _search_batch_jit(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
                       backend: str, interpret: bool | None):
+    """:func:`search_batch` plus the walk's two iteration counts:
+    (found, eid, is_delta, iters, model_iters)."""
     dfound, did = _delta_lookup(ti, qbytes, qlens)
     # a tombstoned delta entry SHADOWS the base: the key is absent until a
     # put resurrects it or merge_delta reconciles the delete (DESIGN.md §9)
     dtomb = dfound & jnp.take(ti.de_tomb, jnp.maximum(did, 0))
-    bfound, beid = base_search_impl(ti, qbytes, qlens, backend, interpret)
+    bfound, beid, iters, model_iters = base_search_impl(
+        ti, qbytes, qlens, backend, interpret)
     found = jnp.where(dfound, ~dtomb, bfound)
     eid = jnp.where(dfound, did, beid)
-    return found, eid, dfound & ~dtomb
+    return found, eid, dfound & ~dtomb, iters, model_iters
 
 
 def search_batch(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
@@ -408,7 +419,7 @@ def search_batch(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
     shadow their base key: such queries report not-found.
     """
     return _search_batch_jit(ti, qbytes, qlens, resolve_search_backend(backend),
-                             interpret)
+                             interpret)[:3]
 
 
 @jax.jit
@@ -579,7 +590,7 @@ def _mutate_batch(ti: TensorIndex, kbytes: jax.Array, klens: jax.Array,
     full (``Status.REJECTED_FULL`` at the facade).
     """
     B, W = kbytes.shape
-    item = _traverse(ti, kbytes, klens)
+    item, _iters, _model_iters = _traverse(ti, kbytes, klens)
     bfound, beid = _resolve_terminal(ti, kbytes, klens, item)
     # update base values in-place (functional); deletes never touch values —
     # they shadow via the delta buffer so merge_delta can reconcile them

@@ -49,26 +49,26 @@ def walk_terminal(
 ):
     """Run the tagged-handle walk until every query sits on a terminal item.
 
-    Returns ``(item, levels)`` — the terminal item per query and the number
-    of levels each query stayed active (roofline accounting).  The
-    ``while_loop`` exits as soon as no query is on a MNODE/TRIE, so a
-    converged batch stops paying per-level cost.
+    Returns ``(item, levels, iters, model_iters)``: the terminal item per
+    query, the number of levels each query stayed active (roofline
+    accounting), and two scalars, the loop iterations the batch ran and how
+    many of them ran the model-node step.  The ``while_loop`` exits as soon
+    as no query is on a MNODE/TRIE, so a converged batch stops paying
+    per-level cost.
+
+    The model-node step (prefix compare, HPT CDF, slot position, ``items``
+    gather) runs only in iterations where some query sits on a MNODE: its
+    ``cdf_steps``-long CDF loop is the costliest part of an iteration, and
+    the deep tail of a walk is critbit sub-trie levels, whose children are
+    never model nodes.  Skipping it changes no result, because a query off
+    a MNODE never takes ``mnext``.
     """
     B = qbytes.shape[0]
     item0 = jnp.broadcast_to(root_item, (B,)).astype(jnp.int32)
 
-    def cond(state):
-        i, item, _ = state
-        tag = item_tag(item)
-        return (i < max_iters) & jnp.any((tag == TAG_MNODE) | (tag == TAG_TRIE))
-
-    def body(state):
-        i, item, levels = state
-        tag = item_tag(item)
-        pay = item_payload(item)
-        active = (tag == TAG_MNODE) | (tag == TAG_TRIE)
+    def model_step(item):
         # ---- model-based node step (paper Alg. 2 `locate`) ----
-        nid = jnp.minimum(pay, mn_slot_base.shape[0] - 1)
+        nid = jnp.minimum(item_payload(item), mn_slot_base.shape[0] - 1)
         pl = jnp.take(mn_prefix_len, nid)
         poff = jnp.take(mn_prefix_off, nid)
         m = jnp.take(mn_slot_cnt, nid)
@@ -80,7 +80,20 @@ def walk_terminal(
             max_steps=cdf_steps,  # §Perf H3: walk only as far as the
         )                         # longest mnode suffix actually stored
         pos = jnp.where(cmp < 0, 0, jnp.where(cmp > 0, m - 1, pos))
-        mnext = jnp.take(items, jnp.minimum(base + pos, items.shape[0] - 1))
+        return jnp.take(items, jnp.minimum(base + pos, items.shape[0] - 1))
+
+    def cond(state):
+        i, item, _, _ = state
+        tag = item_tag(item)
+        return (i < max_iters) & jnp.any((tag == TAG_MNODE) | (tag == TAG_TRIE))
+
+    def body(state):
+        i, item, levels, model_iters = state
+        tag = item_tag(item)
+        pay = item_payload(item)
+        active = (tag == TAG_MNODE) | (tag == TAG_TRIE)
+        on_model = jnp.any(tag == TAG_MNODE)
+        mnext = jax.lax.cond(on_model, model_step, lambda it: it, item)
         # ---- critbit subtrie step ----
         tid = jnp.minimum(pay, tr_byte.shape[0] - 1)
         cb = jnp.take(tr_byte, tid)
@@ -92,11 +105,13 @@ def walk_terminal(
         tnext = jnp.where(bit, jnp.take(tr_right, tid), jnp.take(tr_left, tid))
         item = jnp.where(tag == TAG_MNODE, mnext,
                          jnp.where(tag == TAG_TRIE, tnext, item))
-        return i + 1, item, levels + active.astype(jnp.int32)
+        return (i + 1, item, levels + active.astype(jnp.int32),
+                model_iters + on_model.astype(jnp.int32))
 
-    _, item, levels = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), item0, jnp.zeros((B,), jnp.int32)))
-    return item, levels
+    iters, item, levels, model_iters = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), item0, jnp.zeros((B,), jnp.int32),
+                     jnp.int32(0)))
+    return item, levels, iters, model_iters
 
 
 def resolve_terminal(

@@ -58,7 +58,7 @@ from repro.core.tensor_index import (
     pad_queries,
     resolve_search_backend,
     scan_batch,
-    search_batch,
+    _search_batch_jit,
 )
 from .snapshot import load_index, save_index
 
@@ -272,6 +272,11 @@ class StringIndexBase:
     # device->host syncs on the request path (one per get, put, delete or
     # scan group); backends that do not count them read 0
     host_syncs: int = 0
+    # search-walk loop iterations over all get groups, and those of them
+    # that ran the model-node step (read in the get group's one sync);
+    # None on backends that do not count them
+    walk_iters: Optional[int] = None
+    model_step_iters: Optional[int] = None
 
     def execute(self, batch: Sequence[Request]) -> BatchResult:
         raise NotImplementedError
@@ -306,6 +311,9 @@ class StringIndex(StringIndexBase):
         self._interpret = config.resolved_interpret()
         self.merge_count = 0
         self.host_syncs = 0
+        # the fused Pallas kernel does not count its walk
+        self.walk_iters = self.model_step_iters = (
+            0 if self._backend == "jnp" else None)
         self._host_pool = None         # lazy (key_bytes, ent_off, ent_len) copies
         # None = no merge in flight; a list = the epoch-merge journal: every
         # mutation applied between begin_merge() and commit_merge() is
@@ -406,14 +414,17 @@ class StringIndex(StringIndexBase):
             qb, ql = pad_queries(list(keys), self.ti.width)
             qb, ql = jnp.asarray(qb), jnp.asarray(ql)
         with TraceAnnotation("lits.index.get.dispatch"):
-            found, eid, isd = search_batch(
-                self.ti, qb, ql, backend=self._backend,
-                interpret=self._interpret)
+            found, eid, isd, iters, model_iters = _search_batch_jit(
+                self.ti, qb, ql, self._backend, self._interpret)
             lo, hi = lookup_values(self.ti, eid, isd)
         # ONE host sync for the whole get group
         with TraceAnnotation("lits.index.get.sync"):
-            found, lo, hi = jax.device_get((found, lo, hi))
+            found, lo, hi, iters, model_iters = jax.device_get(
+                (found, lo, hi, iters, model_iters))
         self.host_syncs += 1
+        if iters is not None:
+            self.walk_iters += int(iters)
+            self.model_step_iters += int(model_iters)
         with TraceAnnotation("lits.index.get.decode"):
             return found, np.where(found, _join_values(lo, hi), 0)
 

@@ -77,8 +77,9 @@ def _fused_kernel(
     prob_tab = prob_tab_ref[...]
 
     # the SAME walk + resolve the jnp backend runs (core.walk) — fused here
-    # into one on-chip program with the early-exit convergence loop
-    item, levels = walk_terminal(
+    # into one on-chip program with the early-exit convergence loop; the
+    # walk's iteration counts are the jnp path's counters, unused here
+    item, levels, _iters, _model_iters = walk_terminal(
         qbytes, qlens, root,
         items, mn_base, mn_cnt, mn_poff, mn_plen, mn_alpha, mn_beta,
         tr_byte, tr_mask, tr_left, tr_right,

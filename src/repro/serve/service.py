@@ -109,14 +109,19 @@ class ServiceConfig:
     scan_page_size: int = 64       # default scan_page size
 
 
+# host counters of the backing index that ServiceStats mirrors
+INDEX_COUNTERS = ("host_syncs", "walk_iters", "model_step_iters")
+
+
 @dataclasses.dataclass
 class ServiceStats:
     """Point-in-time service metrics snapshot (one :meth:`IndexService.stats` call).
 
     ``p50_ms``/``p99_ms`` are submit->resolve times over the last
     ``ServiceConfig.latency_window`` submissions.  The ``*_total`` fields
-    and ``host_syncs`` are cumulative since the last ``reset_stats``; the
-    ``mean_*`` and ``syncs_per_flush`` properties divide them by
+    and the index counters (``host_syncs``, ``walk_iters``,
+    ``model_step_iters``) are cumulative since the last ``reset_stats``;
+    the ``mean_*`` and ``syncs_per_flush`` properties divide them by
     ``completed`` or ``flushes``, and differences of two snapshots give the
     same over an interval."""
 
@@ -134,6 +139,10 @@ class ServiceStats:
     queue_wait_ms_total: float = 0.0  # sum over ops of submit -> popped
     flush_ms_total: float = 0.0    # sum over flushes of popped -> resolved
     host_syncs: int = 0            # backing index device syncs on requests
+    # search-walk loop iterations of get groups, and those of them that ran
+    # the model-node step; None where the backing index does not count them
+    walk_iters: Optional[int] = None
+    model_step_iters: Optional[int] = None
     # epoch-based compaction metrics (DESIGN.md §10)
     epoch: int = 0                 # backing index compaction epoch
     merge_pause_ms: float = 0.0    # last commit pause (index lock held)
@@ -279,7 +288,7 @@ class IndexService:
         self._redrained = 0
         self._queue_wait_s = 0.0
         self._flush_s = 0.0
-        self._host_syncs0 = self._index_syncs()
+        self._counters0 = self._index_counters()
         self._flush_seq = 0                     # flusher thread only
         self._maintenance_errors = 0
         self._last_maintenance_error: Optional[str] = None
@@ -578,7 +587,8 @@ class IndexService:
                                    if self._flushes else 0.0),
                 queue_wait_ms_total=self._queue_wait_s * 1e3,
                 flush_ms_total=self._flush_s * 1e3,
-                host_syncs=self._index_syncs() - self._host_syncs0,
+                **{k: None if v is None else v - self._counters0[k]
+                   for k, v in self._index_counters().items()},
                 merges=self._merges,
                 # host mirrors only — stats polling must NEVER sync the
                 # device (delta_fill_fraction would; the facade mirror is
@@ -606,14 +616,14 @@ class IndexService:
             self._merge_wall_ms = 0.0
             self._redrained = 0
             self._queue_wait_s = self._flush_s = 0.0
-            self._host_syncs0 = self._index_syncs()
+            self._counters0 = self._index_counters()
             self._maintenance_errors = 0
             self._last_maintenance_error = None
             self._latencies.clear()
 
-    def _index_syncs(self) -> int:
-        # a host counter on the backing index, never a device read
-        return int(getattr(self.index, "host_syncs", 0))
+    def _index_counters(self) -> Dict[str, Optional[int]]:
+        # host counters on the backing index, never a device read
+        return {k: getattr(self.index, k, None) for k in INDEX_COUNTERS}
 
     @property
     def merge_count(self) -> int:

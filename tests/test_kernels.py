@@ -105,6 +105,7 @@ from repro.core import (  # noqa: E402
     pad_queries, rank_batch, resolve_search_backend, scan_batch, search_batch,
 )
 from repro.core.strings import key_hash16  # noqa: E402
+from repro.core.tensor_index import _search_batch_jit  # noqa: E402
 from repro.kernels.strops import hash16, hash32  # noqa: E402
 
 
@@ -153,17 +154,36 @@ def test_backend_bit_identical(rng, corpus):
         "longkey": _long_key_corpus,
         "mixed": _mixed_corpus,
     }[corpus](rng)
-    b, ti = _build_index(keys)
+    vals = np.arange(len(keys), dtype=np.int64) * 7 - (1 << 33)
+    b, ti = _build_index(keys, vals)
     qb, ql = pad_queries(queries, ti.width)
     qb, ql = jnp.asarray(qb), jnp.asarray(ql)
-    f_j, e_j, d_j = search_batch(ti, qb, ql, backend="jnp")
+    f_j, e_j, d_j, iters, model_iters = _search_batch_jit(ti, qb, ql, "jnp",
+                                                          None)
     f_p, e_p, d_p = search_batch(ti, qb, ql, backend="pallas")
     assert (np.asarray(f_j) == np.asarray(f_p)).all()
     assert (np.asarray(e_j) == np.asarray(e_p)).all()
     assert (np.asarray(d_j) == np.asarray(d_p)).all()
-    # ground truth: found iff the query is a stored key
-    present = np.array([q in set(keys) for q in queries])
+    # ground truth: found iff the query is a stored key, with its value
+    want = dict(zip(keys, vals.tolist()))
+    present = np.array([q in want for q in queries])
     assert (np.asarray(f_j) == present).all()
+    lo, hi = lookup_values(ti, e_j, d_j)
+    got = (np.asarray(hi).astype(np.int64) << 32) | \
+        (np.asarray(lo).astype(np.int64) & 0xFFFFFFFF)
+    assert all(v == want[q] for q, f, v in zip(queries, present, got.tolist())
+               if f)
+    # the walk runs the model-node step only on levels where some query
+    # sits on a model node: the skewed corpus's deep critbit tails give
+    # this batch trie-only levels
+    assert 1 <= int(model_iters) <= int(iters) <= ti.max_iters
+    if corpus == "skewed":
+        assert int(model_iters) < int(iters)
+    # per-query levels of the fused kernel's walk: at least one each, and
+    # the slowest query ran as many levels as the jnp walk's loop
+    _f, _e, levels = ops.fused_search(ti, qb, ql, interpret=True)
+    levels = np.asarray(levels)
+    assert (levels >= 1).all() and int(levels.max()) == int(iters)
 
 
 def test_backend_bit_identical_with_delta_hits(rng):

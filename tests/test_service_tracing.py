@@ -5,6 +5,8 @@
   on the flusher's thread, the flush span carrying its ``flush`` id and
   ``ops``;
 * ``StringIndex.host_syncs`` counts one device sync per op group;
+* ``ServiceStats.walk_iters`` and ``model_step_iters`` mirror the search
+  walk's iteration counts, read in the get group's one sync;
 * ``ServiceStats.queue_wait_ms_total`` sees a flusher held by its index,
   ``flush_ms_total`` the hold itself, and ``reset_stats`` zeroes them.
 """
@@ -157,6 +159,7 @@ def test_queue_wait_counts_a_held_flusher():
     assert s1.mean_queue_wait_ms >= hold_s * 1e3 * queued / (queued + 1)
     assert s1.flush_ms_total >= hold_s * 1e3   # the held flush
     assert s1.host_syncs == 0          # a backend that does not count them
+    assert s1.walk_iters is None and s1.model_step_iters is None
 
 
 def test_reset_stats_zeroes_the_new_counters(rng):
@@ -173,3 +176,15 @@ def test_reset_stats_zeroes_the_new_counters(rng):
     svc.execute([GetRequest(keys[2])])
     assert svc.stats().host_syncs == 1
     svc.close()
+
+
+def test_walk_counters_ride_the_get_sync(rng):
+    svc, keys = _service(rng)
+    for n in (1, 9, 40):
+        got = svc.execute([GetRequest(k) for k in keys[:n]]
+                          + [GetRequest(b"absent-%d" % n)])
+        assert [r.status for r in got] == [Status.OK] * n + [Status.NOT_FOUND]
+    s = svc.stats()
+    svc.close()
+    assert s.flushes == 3 and s.syncs_per_flush == 1.0
+    assert s.walk_iters >= s.model_step_iters >= 1
