@@ -152,6 +152,12 @@ class LITSBuilder:
         self.root_item = make_item(TAG_EMPTY)
         self.n_keys = 0
         self.max_suffix_len = 1  # longest (key - node prefix) any mnode models
+        # build counts since the builder was made: sub-tries by the rule that
+        # chose them (the PMSS decision, the >50% heavy-slot rule, a group the
+        # model cannot split), and keys an mnode models whose suffix is longer
+        # than the MAX_CDF_STEPS bytes the HPT CDF reads
+        self.subtries = {"pmss": 0, "heavy_slot": 0, "unsplittable": 0}
+        self.keys_past_cdf_cap = 0
         self.op_reads = 0
         self.op_writes = 0
         self._cdf_cache_dev = None
@@ -359,7 +365,7 @@ class LITSBuilder:
         if self.cfg.use_subtrie and not force_mnode:
             g = gpkl(ss)
             if self.pmss.decide(g, n) == "trie":
-                return self._build_trie(eids, bytes_mat, lens)
+                return self._build_trie(eids, bytes_mat, lens, "pmss")
         return self._build_mnode(eids, bytes_mat, lens)
 
     def _build_mnode(self, eids: np.ndarray, bytes_mat: np.ndarray, lens: np.ndarray) -> int:
@@ -369,12 +375,13 @@ class LITSBuilder:
         v = self._values(bytes_mat, lens, pl).astype(np.float64)
         vmin, vmax = float(v.min()), float(v.max())
         if not (vmax > vmin):  # model cannot split this group -> trie (strengthened 50% rule)
-            return self._build_trie(eids, bytes_mat, lens)
+            return self._build_trie(eids, bytes_mat, lens, "unsplittable")
         m = int(np.clip(int(self.cfg.slots_factor * n), self.cfg.min_slots, self.cfg.max_slots))
         alpha = np.float32((m - 3) / (vmax - vmin))
         beta = np.float32(1.0 - float(alpha) * vmin)
         pos = self._positions(bytes_mat, lens, pl, float(alpha), float(beta), m)
         self.max_suffix_len = max(self.max_suffix_len, int((lens - pl).max()))
+        self.keys_past_cdf_cap += int(((lens - pl) > MAX_CDF_STEPS).sum())
         base = self.items.extend(np.zeros(m, np.int32))
         nid = self.mn_slot_base.append(base)
         self.mn_slot_cnt.append(m)
@@ -401,7 +408,8 @@ class LITSBuilder:
             if e - s == 1:
                 child = make_item(TAG_ENTRY, int(sub[0]))
             elif (e - s) > self.cfg.heavy_slot_frac * n or (e - s) == n:
-                child = self._build_trie(sub, bytes_mat[s:e], lens[s:e])
+                child = self._build_trie(sub, bytes_mat[s:e], lens[s:e],
+                                         "heavy_slot")
             else:
                 child = self._build_group(sub, bytes_mat[s:e], lens[s:e])
             self.items.data[base + int(pos[s:e].min()):
@@ -416,8 +424,9 @@ class LITSBuilder:
         self.cn_cnt.append(len(eids))
         return make_item(TAG_CNODE, cid)
 
-    def _build_trie(self, eids: np.ndarray, bytes_mat: np.ndarray, lens: np.ndarray) -> int:
-        W = self.width
+    def _build_trie(self, eids: np.ndarray, bytes_mat: np.ndarray, lens: np.ndarray,
+                    route: str) -> int:
+        self.subtries[route] += 1
 
         def rec(lo: int, hi: int) -> int:
             if hi - lo == 1:

@@ -80,7 +80,8 @@ def gen_rands(rng, n: int, lo=2, hi=61) -> List[bytes]:
 # --- "real-like" generators (match Table 1 length stats / Fig. 1 skew) ----
 
 def gen_url(rng, n: int) -> List[bytes]:
-    """CommonCrawl-like URLs: one shared scheme prefix + skewed hosts (avg ~64B)."""
+    """CommonCrawl-like URLs: one shared scheme prefix + skewed hosts (avg ~54B,
+    30-88B; the repo holds no copy of the paper's url figures to match)."""
     tld = [b".com", b".org", b".net", b".de", b".io"]
     hosts = [b"www." + w + tld[rng.integers(0, len(tld))] for w in _words(rng, max(n // 50, 10), 5, 14)]
     paths = _words(rng, 500, 3, 10)

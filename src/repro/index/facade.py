@@ -387,6 +387,18 @@ class StringIndex(StringIndexBase):
         return self._delta_fill
 
     @property
+    def build_counts(self) -> Optional[dict]:
+        """The host builder's counts since it was made: ``subtries`` by the
+        rule that built them (``pmss``, ``heavy_slot``, ``unsplittable``)
+        and ``keys_past_cdf_cap``.  ``None`` when no builder is held (after
+        :meth:`load`, until a merge rebuilds one)."""
+        b = self._builder
+        if b is None:
+            return None
+        return {"subtries": dict(b.subtries),
+                "keys_past_cdf_cap": b.keys_past_cdf_cap}
+
+    @property
     def epoch(self) -> int:
         """Compaction epoch (host mirror of ``ti.epoch``; bumps per merge)."""
         return self._epoch
