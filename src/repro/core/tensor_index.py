@@ -306,7 +306,7 @@ def _traverse(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array):
 
 
 def _resolve_terminal(ti: TensorIndex, qbytes, qlens, item):
-    """EMPTY/ENTRY/CNODE -> (found, eid) (shared impl: core.walk)."""
+    """EMPTY/ENTRY/CNODE -> (found, eid, rounds) (shared impl: core.walk)."""
     return resolve_terminal(
         qbytes, qlens, item,
         ti.cn_base, ti.cn_cnt, ti.ch_hash, ti.ch_ent,
@@ -370,19 +370,20 @@ def base_search_impl(ti: TensorIndex, qbytes, qlens, backend: str = "jnp",
     ``(found, eid)`` — the contract tested in tests/test_kernels.py.
     ``interpret`` overrides the Pallas execution mode (``None`` -> the
     cached ``REPRO_KERNEL_BACKEND`` default).  Returns
-    ``(found, eid, iters, model_iters)``: the last two are the walk's
-    iteration counts (:func:`repro.core.walk.walk_terminal`), ``None`` from
-    the fused Pallas kernel, which does not count them.
+    ``(found, eid, iters, model_iters, rounds)``: the walk's two iteration
+    counts (:func:`repro.core.walk.walk_terminal`) and the terminal
+    resolve's compare rounds (:func:`repro.core.walk.resolve_terminal`),
+    each ``None`` from the fused Pallas kernel, which does not count them.
     """
     if backend == "pallas":
         from repro.kernels import ops as _kops  # lazy: keeps core import light
 
         found, eid, _levels = _kops.fused_search(ti, qbytes, qlens,
                                                  interpret=interpret)
-        return found, eid, None, None
+        return found, eid, None, None, None
     item, iters, model_iters = _traverse(ti, qbytes, qlens)
-    found, eid = _resolve_terminal(ti, qbytes, qlens, item)
-    return found, eid, iters, model_iters
+    found, eid, rounds = _resolve_terminal(ti, qbytes, qlens, item)
+    return found, eid, iters, model_iters, rounds
 
 
 @partial(jax.jit, static_argnames=("backend", "interpret"))
@@ -395,17 +396,17 @@ def base_search(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
 @partial(jax.jit, static_argnames=("backend", "interpret"))
 def _search_batch_jit(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
                       backend: str, interpret: bool | None):
-    """:func:`search_batch` plus the walk's two iteration counts:
-    (found, eid, is_delta, iters, model_iters)."""
+    """:func:`search_batch` plus the search's three counters:
+    (found, eid, is_delta, iters, model_iters, rounds)."""
     dfound, did = _delta_lookup(ti, qbytes, qlens)
     # a tombstoned delta entry SHADOWS the base: the key is absent until a
     # put resurrects it or merge_delta reconciles the delete (DESIGN.md §9)
     dtomb = dfound & jnp.take(ti.de_tomb, jnp.maximum(did, 0))
-    bfound, beid, iters, model_iters = base_search_impl(
+    bfound, beid, iters, model_iters, rounds = base_search_impl(
         ti, qbytes, qlens, backend, interpret)
     found = jnp.where(dfound, ~dtomb, bfound)
     eid = jnp.where(dfound, did, beid)
-    return found, eid, dfound & ~dtomb, iters, model_iters
+    return found, eid, dfound & ~dtomb, iters, model_iters, rounds
 
 
 def search_batch(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
@@ -591,7 +592,7 @@ def _mutate_batch(ti: TensorIndex, kbytes: jax.Array, klens: jax.Array,
     """
     B, W = kbytes.shape
     item, _iters, _model_iters = _traverse(ti, kbytes, klens)
-    bfound, beid = _resolve_terminal(ti, kbytes, klens, item)
+    bfound, beid, _rounds = _resolve_terminal(ti, kbytes, klens, item)
     # update base values in-place (functional); deletes never touch values —
     # they shadow via the delta buffer so merge_delta can reconcile them
     do_base = bfound & ~is_del

@@ -2,8 +2,8 @@
 
 ``walk_terminal`` (tagged dispatch + HPT-CDF locate + critbit step, with the
 early-exit convergence loop and per-query level counter) and
-``resolve_terminal`` (ENTRY string-equality + cnode h-pointer probe) operate
-on flat arrays, so the exact same traced code runs
+``resolve_terminal`` (cnode h-pointer hash scan, then one key-byte compare
+per candidate) operate on flat arrays, so the exact same traced code runs
 
 * in the jnp reference backend (:mod:`repro.core.tensor_index` unpacks the
   ``TensorIndex`` pytree), and
@@ -119,40 +119,55 @@ def resolve_terminal(
     cn_base, cn_cnt, ch_hash, ch_ent, key_bytes, ent_off, ent_len,
     *, cnode_cap: int,
 ):
-    """EMPTY/ENTRY/CNODE terminal item -> (found, eid)."""
+    """EMPTY/ENTRY/CNODE terminal item -> ``(found, eid, rounds)``.
+
+    The 16-bit h-pointer hashes pick the candidates first: an ENTRY's one
+    candidate is its own entry; a CNODE's are the slots among its first
+    ``cnt`` (at most ``cnode_cap``) whose hash equals the query's, in slot
+    order; an EMPTY item, or a CNODE with no hash match, has none and
+    misses without touching a key byte.  Each round then gathers the key
+    bytes of every lane's next candidate once and compares them
+    (``str_eq``); a lane stops at its first equal candidate.  A lane goes
+    on to a second round only if a candidate hashed equal but compared
+    unequal, so ``rounds`` (a scalar: the ``while_loop``'s iterations) is 1
+    for a batch with a hit and no 16-bit collision, and 0 for a batch with
+    no candidate at all.
+    """
     tag = item_tag(item)
     pay = item_payload(item)
-    # ENTRY
-    eid = jnp.minimum(pay, ent_off.shape[0] - 1)
-    ent_ok = (tag == TAG_ENTRY) & str_eq(
-        qbytes, qlens, key_bytes, jnp.take(ent_off, eid), jnp.take(ent_len, eid)
-    )
-    # CNODE: scan up to cnode_cap h-pointers, dereference on 16-bit hash match
+    is_ent = tag == TAG_ENTRY
     cid = jnp.minimum(pay, cn_base.shape[0] - 1)
     base = jnp.take(cn_base, cid)
-    cnt = jnp.take(cn_cnt, cid)
+    cnt = jnp.where(tag == TAG_CNODE, jnp.take(cn_cnt, cid), 0)
     qh = hash16(qbytes, qlens)
+    # (B, cnode_cap) candidates: the h-pointer hash scan, int gathers only;
+    # an ENTRY is a one-slot node with no hash to check
+    j = jnp.arange(cnode_cap, dtype=jnp.int32)[None, :]
+    sidx = jnp.minimum(base[:, None] + j, ch_hash.shape[0] - 1)
+    hmatch = (j < cnt[:, None]) & (jnp.take(ch_hash, sidx) == qh[:, None])
+    pend = hmatch | (is_ent[:, None] & (j == 0))
 
-    def cbody(j, carry):
-        found, feid = carry
-        sidx = jnp.minimum(base + j, ch_hash.shape[0] - 1)
-        h = jnp.take(ch_hash, sidx)
-        cand = jnp.take(ch_ent, sidx)
+    def cond(st):
+        return jnp.any(st[1])
+
+    def body(st):
+        rounds, pend, found, eid = st
+        live = jnp.any(pend, axis=1)
+        slot = jnp.argmax(pend, axis=1).astype(jnp.int32)
+        cand = jnp.where(is_ent, pay, jnp.take(
+            ch_ent, jnp.minimum(base + slot, ch_ent.shape[0] - 1)))
         ce = jnp.minimum(cand, ent_off.shape[0] - 1)
-        hmatch = (j < cnt) & (h == qh) & (tag == TAG_CNODE)
-        eq = hmatch & str_eq(
-            qbytes, qlens, key_bytes, jnp.take(ent_off, ce), jnp.take(ent_len, ce)
-        )
-        take = eq & ~found
-        return found | eq, jnp.where(take, cand, feid)
+        eq = live & str_eq(qbytes, qlens, key_bytes,
+                           jnp.take(ent_off, ce), jnp.take(ent_len, ce))
+        # a hit ends the lane; a collision moves it to its next candidate
+        pend = pend & (j != slot[:, None]) & ~eq[:, None]
+        return rounds + 1, pend, found | eq, jnp.where(eq, ce, eid)
 
     B = qbytes.shape[0]
-    cfound, ceid = jax.lax.fori_loop(
-        0, cnode_cap, cbody, (jnp.zeros((B,), bool), jnp.zeros((B,), jnp.int32))
-    )
-    found = ent_ok | cfound
-    out_eid = jnp.where(ent_ok, eid, jnp.where(cfound, ceid, -1))
-    return found, out_eid
+    rounds, _, found, eid = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), pend, jnp.zeros((B,), bool),
+                     jnp.full((B,), -1, jnp.int32)))
+    return found, eid, rounds
 
 
 def rank_sorted(

@@ -179,7 +179,7 @@ def make_service_fn(sidx: ShardedIndex, mesh, axis: str = "data",
         rl = recvl.reshape(n * C)
         # §Perf H3: serving snapshots are immutable — skip the delta-buffer
         # probe (16 hash probes x W-byte compares per query in search_batch).
-        found, eid, _, _ = base_search_impl(ti, rq, rl, backend, interpret)
+        found, eid, *_ = base_search_impl(ti, rq, rl, backend, interpret)
         lo, hi = lookup_values(ti, eid, jnp.zeros_like(found))
         found = found & (rl > 0)
         # send results home

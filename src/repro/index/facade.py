@@ -277,6 +277,9 @@ class StringIndexBase:
     # None on backends that do not count them
     walk_iters: Optional[int] = None
     model_step_iters: Optional[int] = None
+    # terminal-resolve compare rounds over all get groups (one per group
+    # unless a 16-bit h-pointer hash collides); None where not counted
+    resolve_rounds: Optional[int] = None
 
     def execute(self, batch: Sequence[Request]) -> BatchResult:
         raise NotImplementedError
@@ -311,8 +314,8 @@ class StringIndex(StringIndexBase):
         self._interpret = config.resolved_interpret()
         self.merge_count = 0
         self.host_syncs = 0
-        # the fused Pallas kernel does not count its walk
-        self.walk_iters = self.model_step_iters = (
+        # the fused Pallas kernel does not count its walk or resolve
+        self.walk_iters = self.model_step_iters = self.resolve_rounds = (
             0 if self._backend == "jnp" else None)
         self._host_pool = None         # lazy (key_bytes, ent_off, ent_len) copies
         # None = no merge in flight; a list = the epoch-merge journal: every
@@ -426,17 +429,18 @@ class StringIndex(StringIndexBase):
             qb, ql = pad_queries(list(keys), self.ti.width)
             qb, ql = jnp.asarray(qb), jnp.asarray(ql)
         with TraceAnnotation("lits.index.get.dispatch"):
-            found, eid, isd, iters, model_iters = _search_batch_jit(
+            found, eid, isd, iters, model_iters, rounds = _search_batch_jit(
                 self.ti, qb, ql, self._backend, self._interpret)
             lo, hi = lookup_values(self.ti, eid, isd)
         # ONE host sync for the whole get group
         with TraceAnnotation("lits.index.get.sync"):
-            found, lo, hi, iters, model_iters = jax.device_get(
-                (found, lo, hi, iters, model_iters))
+            found, lo, hi, iters, model_iters, rounds = jax.device_get(
+                (found, lo, hi, iters, model_iters, rounds))
         self.host_syncs += 1
         if iters is not None:
             self.walk_iters += int(iters)
             self.model_step_iters += int(model_iters)
+            self.resolve_rounds += int(rounds)
         with TraceAnnotation("lits.index.get.decode"):
             return found, np.where(found, _join_values(lo, hi), 0)
 

@@ -6,8 +6,9 @@ leaving on-chip memory:
 * tagged-handle dispatch (mnode / critbit-trie / entry / cnode / empty),
 * HPT-CDF walk + per-node linear model + slot clamp (``locate``),
 * critbit subtrie step,
-* compact-leaf 16-bit h-pointer probe (the paper's AVX-512 analogue),
-* final string-equality resolve against the key pool.
+* compact-leaf 16-bit h-pointer hash scan (the paper's AVX-512 analogue),
+* one string-equality compare per hash-matching candidate against the key
+  pool.
 
 The level-synchronous jnp reference in :mod:`repro.core.tensor_index`
 launches one XLA gather cascade per level and re-touches HBM for every
@@ -78,7 +79,8 @@ def _fused_kernel(
 
     # the SAME walk + resolve the jnp backend runs (core.walk) — fused here
     # into one on-chip program with the early-exit convergence loop; the
-    # walk's iteration counts are the jnp path's counters, unused here
+    # walk's iteration counts and the resolve's rounds are the jnp path's
+    # counters, unused here
     item, levels, _iters, _model_iters = walk_terminal(
         qbytes, qlens, root,
         items, mn_base, mn_cnt, mn_poff, mn_plen, mn_alpha, mn_beta,
@@ -86,7 +88,7 @@ def _fused_kernel(
         key_bytes, cdf_tab, prob_tab,
         width=width, max_iters=max_iters, cdf_steps=cdf_steps,
     )
-    found, out_eid = resolve_terminal(
+    found, out_eid, _rounds = resolve_terminal(
         qbytes, qlens, item,
         cn_base, cn_cnt, ch_hash, ch_ent, key_bytes, ent_off, ent_len,
         cnode_cap=cnode_cap,

@@ -110,7 +110,8 @@ class ServiceConfig:
 
 
 # host counters of the backing index that ServiceStats mirrors
-INDEX_COUNTERS = ("host_syncs", "walk_iters", "model_step_iters")
+INDEX_COUNTERS = ("host_syncs", "walk_iters", "model_step_iters",
+                  "resolve_rounds")
 
 
 @dataclasses.dataclass
@@ -120,10 +121,10 @@ class ServiceStats:
     ``p50_ms``/``p99_ms`` are submit->resolve times over the last
     ``ServiceConfig.latency_window`` submissions.  The ``*_total`` fields
     and the index counters (``host_syncs``, ``walk_iters``,
-    ``model_step_iters``) are cumulative since the last ``reset_stats``;
-    the ``mean_*`` and ``syncs_per_flush`` properties divide them by
-    ``completed`` or ``flushes``, and differences of two snapshots give the
-    same over an interval."""
+    ``model_step_iters``, ``resolve_rounds``) are cumulative since the last
+    ``reset_stats``; the ``mean_*`` and ``syncs_per_flush`` properties
+    divide them by ``completed`` or ``flushes``, and differences of two
+    snapshots give the same over an interval."""
 
     submitted: int = 0             # ops admitted into the queue
     completed: int = 0             # ops resolved through a flush
@@ -143,6 +144,9 @@ class ServiceStats:
     # the model-node step; None where the backing index does not count them
     walk_iters: Optional[int] = None
     model_step_iters: Optional[int] = None
+    # terminal-resolve compare rounds of get groups: one per group unless a
+    # 16-bit h-pointer hash collides; None where the index does not count
+    resolve_rounds: Optional[int] = None
     # epoch-based compaction metrics (DESIGN.md §10)
     epoch: int = 0                 # backing index compaction epoch
     merge_pause_ms: float = 0.0    # last commit pause (index lock held)

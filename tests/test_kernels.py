@@ -158,8 +158,8 @@ def test_backend_bit_identical(rng, corpus):
     b, ti = _build_index(keys, vals)
     qb, ql = pad_queries(queries, ti.width)
     qb, ql = jnp.asarray(qb), jnp.asarray(ql)
-    f_j, e_j, d_j, iters, model_iters = _search_batch_jit(ti, qb, ql, "jnp",
-                                                          None)
+    f_j, e_j, d_j, iters, model_iters, rounds = _search_batch_jit(
+        ti, qb, ql, "jnp", None)
     f_p, e_p, d_p = search_batch(ti, qb, ql, backend="pallas")
     assert (np.asarray(f_j) == np.asarray(f_p)).all()
     assert (np.asarray(e_j) == np.asarray(e_p)).all()
@@ -177,6 +177,9 @@ def test_backend_bit_identical(rng, corpus):
     # sits on a model node: the skewed corpus's deep critbit tails give
     # this batch trie-only levels
     assert 1 <= int(model_iters) <= int(iters) <= ti.max_iters
+    # every batch holds a hit, and no lane passes more equal-hash slots
+    # than a cnode has
+    assert 1 <= int(rounds) <= ti.cnode_cap
     if corpus == "skewed":
         assert int(model_iters) < int(iters)
     # per-query levels of the fused kernel's walk: at least one each, and

@@ -7,6 +7,8 @@
 * ``StringIndex.host_syncs`` counts one device sync per op group;
 * ``ServiceStats.walk_iters`` and ``model_step_iters`` mirror the search
   walk's iteration counts, read in the get group's one sync;
+* ``ServiceStats.resolve_rounds`` mirrors the terminal resolve's compare
+  rounds, read in the same sync, and is None on the ``pallas`` backend;
 * ``ServiceStats.queue_wait_ms_total`` sees a flusher held by its index,
   ``flush_ms_total`` the hold itself, and ``reset_stats`` zeroes them.
 """
@@ -31,12 +33,13 @@ def _corpus(rng, n=200):
     return keys, np.arange(len(keys), dtype=np.int64) * 3 + 1
 
 
-def _service(rng, **kw):
+def _service(rng, index=None, **kw):
     keys, vals = _corpus(rng)
     cfg = dict(max_batch=1024, default_tenant="t", merge_threshold=None)
     cfg.update(kw)
     svc = IndexService.bulk_load(
-        {"t": (keys, vals)}, IndexConfig(auto_merge_threshold=None),
+        {"t": (keys, vals)},
+        IndexConfig(auto_merge_threshold=None, **(index or {})),
         ServiceConfig(**cfg))
     return svc, keys
 
@@ -160,6 +163,7 @@ def test_queue_wait_counts_a_held_flusher():
     assert s1.flush_ms_total >= hold_s * 1e3   # the held flush
     assert s1.host_syncs == 0          # a backend that does not count them
     assert s1.walk_iters is None and s1.model_step_iters is None
+    assert s1.resolve_rounds is None
 
 
 def test_reset_stats_zeroes_the_new_counters(rng):
@@ -188,3 +192,31 @@ def test_walk_counters_ride_the_get_sync(rng):
     svc.close()
     assert s.flushes == 3 and s.syncs_per_flush == 1.0
     assert s.walk_iters >= s.model_step_iters >= 1
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_resolve_rounds_ride_the_get_sync(rng, backend):
+    svc, keys = _service(rng, index=dict(search_backend=backend,
+                                         kernel_backend="interpret"))
+    idx = svc.index
+
+    def gets(n):
+        got = svc.execute([GetRequest(k) for k in keys[:n]]
+                          + [GetRequest(b"absent-%d" % n)])
+        assert [r.status for r in got] == \
+            [Status.OK] * n + [Status.NOT_FOUND]
+
+    gets(5)
+    svc.reset_stats()
+    at_reset = idx.resolve_rounds
+    for n in (1, 9, 40):
+        gets(n)
+    s = svc.stats()
+    svc.close()
+    assert s.flushes == 3 and s.syncs_per_flush == 1.0
+    if backend == "pallas":
+        assert s.resolve_rounds is None and idx.resolve_rounds is None
+    else:
+        # every group holds a hit, so it resolves in one round or more
+        assert s.resolve_rounds == idx.resolve_rounds - at_reset
+        assert s.resolve_rounds >= s.host_syncs == 3
