@@ -47,46 +47,36 @@ def bulkload(structure: str, keys: List[bytes]):
     return b, time.perf_counter() - t0
 
 
-def device_read_mops(b, keys: List[bytes], n_queries: int = 8192, reps: int = 5,
-                     backend: str | None = None) -> float:
-    """Batched jitted point-lookup throughput (Mops).
-
-    ``backend`` selects the traversal engine ("jnp" | "pallas"); ``None``
-    resolves from ``REPRO_SEARCH_BACKEND`` — so the YCSB figures can be
-    re-run against the fused kernel without code edits.
-    """
+def device_read_mops(b, keys: List[bytes], n_queries: int = 8192,
+                     reps: int = 5) -> float:
+    """Batched jitted point-lookup throughput (Mops)."""
     ti = freeze(b)
     rng = np.random.default_rng(0)
     idx = rng.integers(0, len(keys), n_queries)
     qb, ql = pad_queries([keys[i] for i in idx], ti.width)
     qb, ql = jnp.asarray(qb), jnp.asarray(ql)
-    found, _, _ = search_batch(ti, qb, ql, backend=backend)  # warmup + correctness
+    found, _, _ = search_batch(ti, qb, ql)  # warmup + correctness
     assert bool(found.all())
     t0 = time.perf_counter()
     for _ in range(reps):
-        out = search_batch(ti, qb, ql, backend=backend)
+        out = search_batch(ti, qb, ql)
     jax.block_until_ready(out)
     dt = time.perf_counter() - t0
     return n_queries * reps / dt / 1e6
 
 
 def device_scan_mops(b, keys: List[bytes], n_queries: int = 2048, window: int = 16,
-                     reps: int = 3, backend: str | None = None) -> float:
-    """Batched jitted range-scan throughput (M entries/s).
-
-    ``backend`` selects the rank engine ("jnp" | fused "pallas"); ``None``
-    resolves from ``REPRO_SEARCH_BACKEND`` — scans no longer silently
-    bypass the fused kernel path.
-    """
+                     reps: int = 3) -> float:
+    """Batched jitted range-scan throughput (M entries/s)."""
     ti = freeze(b)
     rng = np.random.default_rng(1)
     idx = rng.integers(0, len(keys), n_queries)
     qb, ql = pad_queries([keys[i] for i in idx], ti.width)
     qb, ql = jnp.asarray(qb), jnp.asarray(ql)
-    out = scan_batch(ti, qb, ql, window, backend=backend)
+    out = scan_batch(ti, qb, ql, window)
     t0 = time.perf_counter()
     for _ in range(reps):
-        out = scan_batch(ti, qb, ql, window, backend=backend)
+        out = scan_batch(ti, qb, ql, window)
     jax.block_until_ready(out)
     dt = time.perf_counter() - t0
     return n_queries * reps * window / dt / 1e6  # entries/s

@@ -196,8 +196,7 @@ def run_one_chip(args, dev) -> None:
     check_on_device(index.ti, dev, "bulk load")
     info(f"load: {index.n_entries} keys, width {index.width}, "
          f"index.nbytes {index.nbytes()}, host build {t2 - t1:.1f} s, "
-         f"device bytes in use {memory_in_use(dev)}, "
-         f"traversal {index.config.resolved_search_backend()}")
+         f"device bytes in use {memory_in_use(dev)}")
     check(index.n_entries == len(base), "entry count != key count")
 
     oracle = Oracle(base, base_vals.tolist())

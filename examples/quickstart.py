@@ -21,8 +21,8 @@ def main() -> None:
     args = ap.parse_args()
 
     # 1. bulk load (paper Sec. 3.1): sample -> HPT -> collision-driven build.
-    #    IndexConfig is the one policy object: backends, delta sizing, merge
-    #    threshold — env vars (REPRO_SEARCH_BACKEND, ...) are only defaults.
+    #    IndexConfig is the one policy object: delta sizing, merge threshold,
+    #    scan window.
     keys = sorted(set(load("email", args.n, seed=0)))
     values = np.arange(len(keys), dtype=np.int64) * 10
     cfg = IndexConfig(delta_capacity=2048, auto_merge_threshold=0.75)

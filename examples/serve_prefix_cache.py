@@ -18,7 +18,7 @@ def main() -> None:
     model = LMModel(cfg)
     params = model.init(jax.random.PRNGKey(0))
     # one IndexConfig drives the prompt-cache index end to end (DESIGN.md §8):
-    # traversal backend, delta sizing and the auto-compaction threshold
+    # width, delta sizing and the auto-compaction threshold
     eng = ServeEngine(model, params,
                       index_config=IndexConfig(width=256, delta_capacity=1024,
                                                auto_merge_threshold=0.75))

@@ -44,7 +44,7 @@ def main() -> None:
     print(f"arch={cfg.name} params={cfg.param_count() / 1e6:.1f}M")
 
     # LITS in the data path: dedup incoming shard manifests by string id
-    # (StringIndex facade underneath; IndexConfig picks the backends)
+    # (StringIndex facade underneath; IndexConfig sizes the delta)
     store = RecordStore([b"shard-%05d" % i for i in range(1000)],
                         config=IndexConfig(delta_capacity=512))
     incoming = [b"shard-%05d" % i for i in range(990, 1010)]

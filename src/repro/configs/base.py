@@ -258,54 +258,3 @@ def input_specs(cfg: ArchConfig, shape: ShapeSpec) -> dict:
         "token": jax.ShapeDtypeStruct((B,), jnp.int32),
         "pos": jax.ShapeDtypeStruct((), jnp.int32),
     }
-
-
-# ---------------------------------------------------------------------------
-# index runtime (traversal-backend contract, DESIGN.md §7)
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class IndexRuntimeConfig:
-    """How LITS query paths execute on this host.
-
-    .. note:: application code should carry these choices in
-       :class:`repro.index.IndexConfig` (``search_backend`` /
-       ``kernel_backend``, DESIGN.md §8); this dataclass remains for
-       launch-grid plumbing that predates the facade.
-
-    ``search_backend`` picks the traversal engine for ``search_batch`` /
-    ``base_search`` ("jnp" = bitwise-reference oracle, "pallas" = fused
-    single-kernel engine); ``kernel_mode`` picks how Pallas kernels execute
-    ("auto" | "interpret" | "native").  ``from_env`` mirrors the env-var
-    contract (``REPRO_SEARCH_BACKEND`` / ``REPRO_KERNEL_BACKEND``) so CPU
-    containers and TPU pods pick the right path without code edits.
-    """
-
-    search_backend: str = "jnp"   # jnp | pallas
-    kernel_mode: str = "auto"     # auto | interpret | native
-    block_b: int = 256            # query rows per fused-kernel grid step
-
-    @staticmethod
-    def from_env() -> "IndexRuntimeConfig":
-        import os
-
-        def _get(var: str, default: str) -> str:
-            # same normalization as tensor_index.resolve_search_backend /
-            # kernels.ops._interpret_default: strip first, THEN fall back,
-            # so a whitespace-only value means "use the default"
-            return os.environ.get(var, default).strip().lower() or default
-
-        return IndexRuntimeConfig(
-            search_backend=_get("REPRO_SEARCH_BACKEND", "jnp"),
-            kernel_mode=_get("REPRO_KERNEL_BACKEND", "auto"),
-        )
-
-    def validate(self) -> "IndexRuntimeConfig":
-        from repro.core.tensor_index import resolve_search_backend
-        from repro.kernels.ops import resolve_interpret
-
-        # the same resolvers the query paths use: unknown names raise
-        # ValueError, interpret mode on a TPU backend raises RuntimeError
-        resolve_search_backend(self.search_backend)
-        resolve_interpret(self.kernel_mode)
-        return self

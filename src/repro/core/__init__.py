@@ -15,7 +15,6 @@ from .hpt import HPT, build_hpt, get_cdf_jnp, get_cdf_np64, positions_jnp, unifo
 from .pmss import PMSS, AlwaysLIT, AlwaysTrie
 from .strings import StringSet, sort_order
 from .tensor_index import (
-    SEARCH_BACKENDS,
     TensorIndex,
     base_search,
     delete_batch,
@@ -25,7 +24,6 @@ from .tensor_index import (
     merge_delta,
     pad_queries,
     rank_batch,
-    resolve_search_backend,
     scan_batch,
     search_batch,
 )
@@ -37,6 +35,5 @@ __all__ = [
     "TensorIndex", "freeze", "search_batch", "base_search", "insert_batch",
     "delete_batch", "lookup_values", "merge_delta", "pad_queries",
     "rank_batch", "scan_batch",
-    "SEARCH_BACKENDS", "resolve_search_backend",
     "TAG_EMPTY", "TAG_ENTRY", "TAG_MNODE", "TAG_CNODE", "TAG_TRIE",
 ]

@@ -31,8 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .strings import StringSet
-
-FNV_PRIME = np.uint32(0x01000193)
+from .strops import FNV_PRIME
 
 # Maximum number of characters the CDF walk consumes.  Beyond ~48 characters
 # the running float32 ``prob`` underflows for any realistic distribution, so
@@ -67,7 +66,7 @@ def _check_pow2(x: int, name: str) -> None:
 
 
 def rolling_hash_np(h: np.ndarray, c: np.ndarray) -> np.ndarray:
-    """One rolling-hash step (uint32 wraparound); identical to the jnp/Pallas one."""
+    """One rolling-hash step (uint32 wraparound); identical to the device one."""
     return ((h ^ c.astype(np.uint32)) * FNV_PRIME).astype(np.uint32)
 
 

@@ -161,12 +161,12 @@ def compare_to(ss: StringSet, key: bytes) -> np.ndarray:
 def key_hash16(bytes_mat: np.ndarray, lens: np.ndarray) -> np.ndarray:
     """16-bit FNV-1a style hash of each key (the paper's h-pointer hash).
 
-    Must match the device implementation (``repro.kernels.strops.hash16``)
+    Must match the device implementation (``repro.core.strops.hash16``)
     bit-for-bit: uint32 arithmetic, truncated to 16 bits at the end, over
     exactly ``min(len, width)`` bytes where ``width = bytes_mat.shape[1]``.
     Device/host agreement therefore requires hashing through a matrix of the
     *index* width — keys longer than the index width are unrepresentable and
-    are rejected at insert time on both paths (tested in test_kernels.py).
+    are rejected at insert time on both paths (tested in test_device_ops.py).
     """
     h = np.full(bytes_mat.shape[0], 0x811C9DC5, dtype=np.uint32)
     for k in range(bytes_mat.shape[1]):

@@ -1,11 +1,9 @@
-"""Shared device string primitives (jnp) used by BOTH traversal backends.
+"""Device string primitives (jnp): byte gathers, compares and FNV hashes.
 
-These functions are plain jnp and trace identically inside a jitted host
-program and inside a Pallas kernel body (interpret or native), so the
-pure-jnp reference path in :mod:`repro.core.tensor_index` and the fused
-Pallas kernel in :mod:`repro.kernels.traverse` literally share one
-implementation — the backend-equivalence contract (DESIGN.md §7) reduces
-to "same code, same op order".
+The traversal walk (:mod:`repro.core.walk`), the delta probe and mutate
+paths (:mod:`repro.core.tensor_index`) and the mesh router
+(:mod:`repro.distributed.index_service`) all compare and hash keys through
+these functions, so every path agrees on key order and hash values.
 
 Hash semantics contract
 -----------------------
@@ -18,8 +16,8 @@ representable (``pad_queries`` marks them with the ``width+1`` length
 sentinel and ``insert_batch`` rejects them), so a stored hash never covers
 truncated bytes.
 
-This module must stay a leaf import: no ``repro.core`` imports here
-(``repro.core.tensor_index`` imports us).
+This module stays a leaf: it imports nothing from the rest of
+:mod:`repro.core` (``walk`` and ``tensor_index`` import it).
 """
 from __future__ import annotations
 
@@ -27,9 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# FNV-1a constants — the single authoritative definition for device code.
-# (`repro.core.hpt.FNV_PRIME` is the same value; kernels import from here to
-# keep the kernels package free of core imports.)
+# FNV-1a constants — the single definition (``repro.core.hpt`` imports it).
 FNV_PRIME = np.uint32(0x01000193)
 FNV_OFFSET = np.uint32(0x811C9DC5)
 

@@ -5,7 +5,7 @@ flat jax arrays) from a host :class:`~repro.core.builder.LITSBuilder`.  All
 query-side operations are single jitted functions, composable under
 ``vmap``/``pjit``/``shard_map``:
 
-* :func:`search_batch`   — paper Alg. 2, batched traversal (pluggable backend)
+* :func:`search_batch`   — paper Alg. 2, batched traversal
 * :func:`base_search`    — traversal + terminal resolve, no delta probe
 * :func:`rank_batch`     — ordered rank for range scans (binary search)
 * :func:`scan_batch`     — delta-aware range scans (read-your-writes: a
@@ -16,7 +16,9 @@ query-side operations are single jitted functions, composable under
 * :func:`lookup_values`  — (lo, hi) 2×int32 value fetch
 
 The traversal mirrors the host builder bit-for-bit: slot positions come from
-the same float32 ``positions_impl`` the builder used at build time.
+the same float32 ``positions_impl`` the builder used at build time.  The
+walk itself lives in :mod:`repro.core.walk`, the string primitives in
+:mod:`repro.core.strops`.
 
 .. note:: **Legacy surface.**  These free functions are the jitted
    primitives underneath :class:`repro.index.StringIndex` (DESIGN.md §8) —
@@ -25,27 +27,11 @@ the same float32 ``positions_impl`` the builder used at build time.
    through the facade; this module stays stable as the kernel-level seam
    the facade (and power users) compose.
 
-Traversal backends (DESIGN.md §7)
----------------------------------
-``search_batch``/``base_search``/``rank_batch``/``scan_batch`` take
-``backend="jnp" | "pallas"``:
-
-* ``jnp``    — the level-synchronous pure-jnp reference (the bitwise oracle),
-* ``pallas`` — the fused single-kernel engines (:mod:`repro.kernels.traverse`
-  for point lookups, :mod:`repro.kernels.rank` for ordered rank/scan),
-  bit-identical by construction (shared primitives).
-
-``backend=None`` resolves once from the ``REPRO_SEARCH_BACKEND`` environment
-variable (default ``jnp``); the optional ``interpret`` argument overrides
-the ``REPRO_KERNEL_BACKEND`` Pallas execution mode per call.  String
-primitives live in :mod:`repro.kernels.strops`, shared verbatim by both
-backends.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-import os
 from functools import partial
 from typing import Tuple
 
@@ -65,13 +51,8 @@ from .builder import (
 )
 from .hpt import MAX_CDF_STEPS, get_cdf_impl
 from .walk import rank_sorted, resolve_terminal, scan_merged, walk_terminal
-from repro.kernels.strops import (
-    gather_bytes as _gather_bytes,
-    hash16 as _hash16,
-    hash32 as _hash32,
-    str_cmp_full as _str_cmp_full,
-    str_cmp_prefix as _str_cmp_prefix,
-    str_eq as _str_eq,
+from .strops import (
+    gather_bytes as _gather_bytes, hash32 as _hash32, str_eq as _str_eq,
 )
 
 
@@ -266,16 +247,6 @@ def pad_queries(keys, width: int) -> Tuple[np.ndarray, np.ndarray]:
     return qb, ql
 
 
-# ---------------------------------------------------------------------------
-# device string primitives — shared with the Pallas kernels
-# ---------------------------------------------------------------------------
-# ``_gather_bytes``/``_str_eq``/``_str_cmp_prefix``/``_str_cmp_full``/
-# ``_hash16``/``_hash32`` are imported from :mod:`repro.kernels.strops` (see
-# module docstring): one implementation serves the jnp reference backend and
-# the fused Pallas traversal kernel, which is what makes backend equivalence
-# a bit-exact identity rather than a tolerance.
-
-
 def _tag(item: jax.Array) -> jax.Array:
     return jax.lax.shift_right_logical(item, PAYLOAD_BITS) & 0x7
 
@@ -340,62 +311,30 @@ def _delta_lookup(ti: TensorIndex, qbytes, qlens):
 
 
 # ---------------------------------------------------------------------------
-# pluggable traversal backend (DESIGN.md §7)
+# point lookup
 # ---------------------------------------------------------------------------
 
-SEARCH_BACKENDS = ("jnp", "pallas")
-
-
-def resolve_search_backend(backend: str | None = None) -> str:
-    """Resolve the traversal backend: explicit arg > env > ``"jnp"``.
-
-    ``jnp`` is the bitwise-reference oracle; ``pallas`` is the fused
-    single-kernel engine.  Set ``REPRO_SEARCH_BACKEND=pallas`` to switch a
-    whole process (serving containers, benchmarks) without code edits.
-    """
-    if backend is None:
-        backend = os.environ.get("REPRO_SEARCH_BACKEND", "jnp").strip().lower() or "jnp"
-    if backend not in SEARCH_BACKENDS:
-        raise ValueError(
-            f"unknown traversal backend {backend!r}; expected one of {SEARCH_BACKENDS}")
-    return backend
-
-
-def base_search_impl(ti: TensorIndex, qbytes, qlens, backend: str = "jnp",
-                     interpret: bool | None = None):
+def base_search_impl(ti: TensorIndex, qbytes, qlens):
     """Traversal + terminal resolve over the frozen base index (no delta probe).
 
-    Traceable (usable inside jit / shard_map); ``backend`` must already be
-    resolved to a concrete value.  Both backends return bit-identical
-    ``(found, eid)`` — the contract tested in tests/test_kernels.py.
-    ``interpret`` overrides the Pallas execution mode (``None`` -> the
-    cached ``REPRO_KERNEL_BACKEND`` default).  Returns
+    Traceable (usable inside jit / shard_map).  Returns
     ``(found, eid, iters, model_iters, rounds)``: the walk's two iteration
     counts (:func:`repro.core.walk.walk_terminal`) and the terminal
-    resolve's compare rounds (:func:`repro.core.walk.resolve_terminal`),
-    each ``None`` from the fused Pallas kernel, which does not count them.
+    resolve's compare rounds (:func:`repro.core.walk.resolve_terminal`).
     """
-    if backend == "pallas":
-        from repro.kernels import ops as _kops  # lazy: keeps core import light
-
-        found, eid, _levels = _kops.fused_search(ti, qbytes, qlens,
-                                                 interpret=interpret)
-        return found, eid, None, None, None
     item, iters, model_iters = _traverse(ti, qbytes, qlens)
     found, eid, rounds = _resolve_terminal(ti, qbytes, qlens, item)
     return found, eid, iters, model_iters, rounds
 
 
-@partial(jax.jit, static_argnames=("backend", "interpret"))
-def base_search(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
-                backend: str = "jnp", interpret: bool | None = None):
+@jax.jit
+def base_search(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array):
     """Jitted :func:`base_search_impl` (snapshot search, delta skipped)."""
-    return base_search_impl(ti, qbytes, qlens, backend, interpret)[:2]
+    return base_search_impl(ti, qbytes, qlens)[:2]
 
 
-@partial(jax.jit, static_argnames=("backend", "interpret"))
-def _search_batch_jit(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
-                      backend: str, interpret: bool | None):
+@jax.jit
+def _search_batch_jit(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array):
     """:func:`search_batch` plus the search's three counters:
     (found, eid, is_delta, iters, model_iters, rounds)."""
     dfound, did = _delta_lookup(ti, qbytes, qlens)
@@ -403,24 +342,20 @@ def _search_batch_jit(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
     # put resurrects it or merge_delta reconciles the delete (DESIGN.md §9)
     dtomb = dfound & jnp.take(ti.de_tomb, jnp.maximum(did, 0))
     bfound, beid, iters, model_iters, rounds = base_search_impl(
-        ti, qbytes, qlens, backend, interpret)
+        ti, qbytes, qlens)
     found = jnp.where(dfound, ~dtomb, bfound)
     eid = jnp.where(dfound, did, beid)
     return found, eid, dfound & ~dtomb, iters, model_iters, rounds
 
 
-def search_batch(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
-                 *, backend: str | None = None, interpret: bool | None = None):
+def search_batch(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array):
     """Batched point lookup. Returns (found, eid, is_delta).
 
-    ``backend`` picks the traversal engine (``"jnp"`` reference or fused
-    ``"pallas"`` kernel); ``None`` resolves from ``REPRO_SEARCH_BACKEND``.
-    The delta-buffer probe always runs on the jnp path (mutable state stays
-    outside the kernel).  Tombstoned delta entries (see :func:`delete_batch`)
-    shadow their base key: such queries report not-found.
+    The delta-buffer probe runs ahead of the base walk.  Tombstoned delta
+    entries (see :func:`delete_batch`) shadow their base key: such queries
+    report not-found.
     """
-    return _search_batch_jit(ti, qbytes, qlens, resolve_search_backend(backend),
-                             interpret)[:3]
+    return _search_batch_jit(ti, qbytes, qlens)[:3]
 
 
 @jax.jit
@@ -440,41 +375,15 @@ def lookup_values(ti: TensorIndex, eid: jax.Array, is_delta: jax.Array):
 # ordered rank + scan (over the frozen sorted entry order)
 # ---------------------------------------------------------------------------
 
-def rank_batch_impl(ti: TensorIndex, qbytes, qlens, backend: str = "jnp",
-                    interpret: bool | None = None) -> jax.Array:
-    """Ordered rank, traceable; ``backend`` must be a resolved concrete value.
-
-    Both backends run the shared :func:`repro.core.walk.rank_sorted` binary
-    search, so ranks are bit-identical (``jnp`` reference vs the fused
-    ``pallas`` kernel in :mod:`repro.kernels.rank`).
-    """
-    if backend == "pallas":
-        from repro.kernels import ops as _kops  # lazy: keeps core import light
-
-        return _kops.fused_rank(ti, qbytes, qlens, interpret=interpret)
+@jax.jit
+def rank_batch(ti: TensorIndex, qbytes: jax.Array,
+               qlens: jax.Array) -> jax.Array:
+    """First rank r such that key(ent_sorted[r]) >= query (binary search,
+    :func:`repro.core.walk.rank_sorted`)."""
     return rank_sorted(
         qbytes, qlens, ti.ent_sorted, ti.ent_off, ti.ent_len, ti.key_bytes,
         rank_iters=ti.rank_iters,
     )
-
-
-@partial(jax.jit, static_argnames=("backend", "interpret"))
-def _rank_batch_jit(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
-                    backend: str, interpret: bool | None) -> jax.Array:
-    return rank_batch_impl(ti, qbytes, qlens, backend, interpret)
-
-
-def rank_batch(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
-               *, backend: str | None = None,
-               interpret: bool | None = None) -> jax.Array:
-    """First rank r such that key(ent_sorted[r]) >= query (binary search).
-
-    ``backend`` routes through the same :func:`resolve_search_backend` path
-    as :func:`base_search`, so range scans can use the fused Pallas rank
-    kernel instead of always falling back to jnp.
-    """
-    return _rank_batch_jit(ti, qbytes, qlens, resolve_search_backend(backend),
-                           interpret)
 
 
 def _scan_n_base(ti: TensorIndex) -> jax.Array:
@@ -487,14 +396,9 @@ def _scan_n_base(ti: TensorIndex) -> jax.Array:
                      jnp.int32(ti.ent_sorted.shape[0]), jnp.int32(0))
 
 
-@partial(jax.jit, static_argnames=("window", "backend", "interpret"))
+@partial(jax.jit, static_argnames=("window",))
 def _scan_batch_jit(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
-                    window: int, backend: str, interpret: bool | None):
-    if backend == "pallas":
-        from repro.kernels import ops as _kops  # lazy: keeps core import light
-
-        return _kops.fused_scan(ti, qbytes, qlens, window=window,
-                                interpret=interpret)
+                    window: int):
     return scan_merged(
         qbytes, qlens,
         ti.ent_sorted, ti.ent_off, ti.ent_len, ti.key_bytes, _scan_n_base(ti),
@@ -503,8 +407,7 @@ def _scan_batch_jit(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
 
 
 def scan_batch(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
-               window: int = 16, *, backend: str | None = None,
-               interpret: bool | None = None):
+               window: int = 16):
     """Delta-aware range scan: the next ``window`` keys >= query in the LIVE
     index order — read-your-writes (DESIGN.md §11).
 
@@ -515,14 +418,8 @@ def scan_batch(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array,
     put serves the delta value).  ``eids`` indexes the base entry pools
     where ``~is_delta`` and the delta pools where ``is_delta`` — exactly
     the :func:`lookup_values` contract, so value fetch is unchanged.
-
-    ``backend`` selects the engine: the ``"jnp"`` reference or the fused
-    ``"pallas"`` rank+merge kernel (:mod:`repro.kernels.scan`) — both run
-    the shared :func:`repro.core.walk.scan_merged`, so results are
-    bit-identical by construction.  ``None`` -> ``REPRO_SEARCH_BACKEND``.
     """
-    return _scan_batch_jit(ti, qbytes, qlens, window,
-                           resolve_search_backend(backend), interpret)
+    return _scan_batch_jit(ti, qbytes, qlens, window)
 
 
 # ---------------------------------------------------------------------------

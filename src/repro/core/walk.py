@@ -1,17 +1,12 @@
-"""The Alg. 2 walk over flat pools — ONE implementation for both backends.
+"""The Alg. 2 walk over flat pools: point lookup, rank and range scan.
 
 ``walk_terminal`` (tagged dispatch + HPT-CDF locate + critbit step, with the
 early-exit convergence loop and per-query level counter) and
 ``resolve_terminal`` (cnode h-pointer hash scan, then one key-byte compare
-per candidate) operate on flat arrays, so the exact same traced code runs
-
-* in the jnp reference backend (:mod:`repro.core.tensor_index` unpacks the
-  ``TensorIndex`` pytree), and
-* inside the fused Pallas kernel body (:mod:`repro.kernels.traverse` loads
-  the same pools from VMEM refs).
-
-This is what makes the backend bit-identity contract (DESIGN.md §7)
-structural: there is no second copy of the traversal to drift.
+per candidate) operate on flat arrays; :mod:`repro.core.tensor_index`
+unpacks the ``TensorIndex`` pytree into them.  ``rank_sorted`` and
+``scan_merged`` serve ordered rank and the delta-aware range scan the same
+way.
 """
 from __future__ import annotations
 
@@ -27,7 +22,7 @@ from .builder import (
     TAG_TRIE,
 )
 from .hpt import positions_impl
-from repro.kernels.strops import (
+from .strops import (
     hash16, str_cmp_full, str_cmp_pools, str_cmp_prefix, str_eq,
 )
 
@@ -176,9 +171,8 @@ def rank_sorted(
 ):
     """First rank r such that key(ent_sorted[r]) >= query (binary search).
 
-    Flat-pool implementation shared by the jnp reference (`rank_batch`) and
-    the fused Pallas rank kernel (:mod:`repro.kernels.rank`) — the same
-    structural bit-identity contract as ``walk_terminal`` (DESIGN.md §7).
+    Flat-pool implementation under :func:`repro.core.tensor_index.rank_batch`
+    and the delta-aware scan.
 
     ``n_live`` (a traced scalar) bounds the search to the first ``n_live``
     rows of ``ent_sorted`` — used by the delta-aware scan to rank into the
@@ -242,9 +236,8 @@ def scan_merged(
     Runs as ONE ``while_loop`` over the whole batch with an early-exit
     condition (a lane stops once its window is full or both streams are
     exhausted), so a converged batch stops paying per-step cost — the same
-    shape as ``walk_terminal``.  Shared verbatim by the jnp reference
-    (:func:`repro.core.tensor_index.scan_batch`) and the fused Pallas scan
-    kernel (:mod:`repro.kernels.scan`): backend bit-identity is structural.
+    shape as ``walk_terminal``.  Runs under
+    :func:`repro.core.tensor_index.scan_batch`.
 
     Returns ``(eids, valid, is_delta)``, each ``(B, window)``; ``eids``
     indexes the base entry pools where ``~is_delta`` and the delta entry

@@ -72,7 +72,6 @@ class RecordStore:
     """
 
     def __init__(self, keys: List[bytes], payloads: Optional[np.ndarray] = None,
-                 backend: Optional[str] = None,
                  config: Optional[IndexConfig] = None,
                  service=None, tenant: Optional[str] = None):
         from repro.serve.service import IndexService
@@ -82,9 +81,6 @@ class RecordStore:
         if service is None:
             vals = (np.arange(len(keys), dtype=np.int64) if payloads is None
                     else np.asarray(payloads, np.int64))
-            if config is None:
-                # legacy shorthand: just the traversal backend
-                config = IndexConfig(search_backend=backend)
             # bulk load under the store's tenant namespace so the typed ops
             # (which the service tenant-prefixes) see the corpus
             service = IndexService.bulk_load(

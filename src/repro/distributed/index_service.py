@@ -31,12 +31,11 @@ import numpy as np
 
 from repro.core import LITSBuilder, StringSet, freeze, lookup_values
 from repro.core.strings import sort_order
+from repro.core.strops import str_cmp_full
 from repro.core.tensor_index import (
-    STATIC_FIELDS, TensorIndex, base_search_impl, pad_queries,
-    resolve_search_backend, scan_batch,
+    STATIC_FIELDS, TensorIndex, base_search_impl, pad_queries, scan_batch,
 )
 from repro.index import StringIndexBase
-from repro.kernels.strops import str_cmp_full
 
 
 class RoutingOverflowError(RuntimeError):
@@ -127,22 +126,17 @@ def _slice_shard(stacked: TensorIndex, s) -> TensorIndex:
 
 
 def make_service_fn(sidx: ShardedIndex, mesh, axis: str = "data",
-                    per_dest_capacity: int = 256, shard_axes=None,
-                    backend: str | None = None,
-                    interpret: bool | None = None):
+                    per_dest_capacity: int = 256, shard_axes=None):
     """Returns a jitted shard_map fn: (qbytes, qlens) -> (found, lo, hi, overflow).
 
     ``axis`` is the partition axis of the index (all_to_all routing axis);
     ``shard_axes`` (default: just ``axis``) are the mesh axes the *query rows*
     are sharded over — extra axes act as serving replicas (the index is
-    replicated across them).  ``backend`` selects the local traversal engine
-    (DESIGN.md §7); ``None`` resolves from ``REPRO_SEARCH_BACKEND``.
-    ``interpret`` overrides the Pallas execution mode (None -> env).
+    replicated across them).
     """
     from jax.sharding import PartitionSpec as P
 
     shard_axes = (axis,) if shard_axes is None else tuple(shard_axes)
-    backend = resolve_search_backend(backend)
 
     n = sidx.n_shards
     C = per_dest_capacity
@@ -179,7 +173,7 @@ def make_service_fn(sidx: ShardedIndex, mesh, axis: str = "data",
         rl = recvl.reshape(n * C)
         # §Perf H3: serving snapshots are immutable — skip the delta-buffer
         # probe (16 hash probes x W-byte compares per query in search_batch).
-        found, eid, *_ = base_search_impl(ti, rq, rl, backend, interpret)
+        found, eid, *_ = base_search_impl(ti, rq, rl)
         lo, hi = lookup_values(ti, eid, jnp.zeros_like(found))
         found = found & (rl > 0)
         # send results home
@@ -256,8 +250,7 @@ class DistributedStringIndex(StringIndexBase):
         #                               (immutable snapshot: cache is safe)
         self._fn = make_service_fn(
             self.sidx, mesh, axis=axis, per_dest_capacity=per_dest_capacity,
-            shard_axes=shard_axes, backend=self.config.search_backend,
-            interpret=self.config.resolved_interpret())
+            shard_axes=shard_axes)
 
     @classmethod
     def build(cls, keys: List[bytes], values: np.ndarray, n_shards: int,
@@ -335,26 +328,23 @@ class DistributedStringIndex(StringIndexBase):
         """Range scans: per-query lists of ``(key, value)`` pairs — the next
         ``window`` keys >= each start across ALL shards.
 
-        Every shard runs the local ``scan_batch`` engine on its slice
-        (backend per ``config``), pinned to the FROZEN stream: like the
-        shard-side GET path, serving scans skip the delta region — a
-        hand-built stacked index carrying unmerged delta entries must not
-        scan keys that shard-side GETs cannot see (and whose bytes live
-        outside the cached base-pool mirrors).  Shards are contiguous key
-        ranges (§5), so shard ``s``'s window sorts entirely before shard
-        ``s+1``'s — per-shard windows concatenate in shard order and the
-        first ``window`` survivors are the global answer.  Shards whose
-        range ends below a query return empty windows and drop out; a
-        smarter router would skip them up front (future work), correctness
-        does not depend on it.
+        Every shard runs the local ``scan_batch`` engine on its slice,
+        pinned to the FROZEN stream: like the shard-side GET path, serving
+        scans skip the delta region — a hand-built stacked index carrying
+        unmerged delta entries must not scan keys that shard-side GETs
+        cannot see (and whose bytes live outside the cached base-pool
+        mirrors). Shards are contiguous key ranges (§5), so shard ``s``'s
+        window sorts entirely before shard ``s+1``'s — per-shard windows
+        concatenate in shard order and the first ``window`` survivors are
+        the global answer. Shards whose range ends below a query return
+        empty windows and drop out; a smarter router would skip them up
+        front (future work), correctness does not depend on it.
         """
         B = len(starts)
         if B == 0:
             return []
         qb, ql = pad_queries(list(starts), self.sidx.width)
         qb, ql = jnp.asarray(qb), jnp.asarray(ql)
-        backend = resolve_search_backend(self.config.search_backend)
-        interpret = self.config.resolved_interpret()
         out = [[] for _ in range(B)]
         for s in range(self.sidx.n_shards):
             if all(len(o) >= window for o in out):
@@ -363,9 +353,7 @@ class DistributedStringIndex(StringIndexBase):
             # frozen-only: zero the delta stream bound (§11 — the scan
             # merge short-circuits to the contiguous frozen window)
             ti = dataclasses.replace(ti, de_count=jnp.zeros((), jnp.int32))
-            eids, valid, _isd = scan_batch(ti, qb, ql, window,
-                                           backend=backend,
-                                           interpret=interpret)
+            eids, valid, _isd = scan_batch(ti, qb, ql, window)
             vlo, vhi = lookup_values(ti, jnp.maximum(eids, 0),
                                      jnp.zeros_like(valid))
             eids, valid, vlo, vhi = (np.asarray(x) for x in jax.device_get(

@@ -4,9 +4,7 @@ One object owns the full index lifecycle that was previously scattered over
 ~10 free functions and two environment variables:
 
 * :class:`IndexConfig` — unified configuration (width, delta-pool sizing,
-  kernel/search backends, auto-compaction policy).  Environment variables
-  (``REPRO_SEARCH_BACKEND``, ``REPRO_KERNEL_BACKEND``) become *defaults*;
-  an explicit config field always wins.
+  auto-compaction policy, scan window).
 * :meth:`StringIndex.bulk_load` — paper Sec. 3.1 bulkload to a frozen
   device index.
 * Typed batched ops — :class:`GetRequest` / :class:`PutRequest` /
@@ -26,12 +24,11 @@ Batch semantics (the planning contract tested in
 tests/test_string_index.py): within one ``execute`` call, **puts apply
 first**, then gets and scans observe the post-put index — i.e. the batch is
 equivalent to the legacy sequence ``insert_batch(all puts)`` →
-``search_batch(all gets)`` → ``scan_batch(all scans)``, bit-identically on
-both traversal backends.  Gets see fresh puts through the delta probe, and
-scans are **read-your-writes** too (DESIGN.md §11): ``scan_batch`` merges
-the live delta view into the frozen order, so unmerged inserts appear
-immediately and deleted keys never scan — point and range reads agree on
-every epoch.
+``search_batch(all gets)`` → ``scan_batch(all scans)``, bit-identically.
+Gets see fresh puts through the delta probe, and scans are
+**read-your-writes** too (DESIGN.md §11): ``scan_batch`` merges the live
+delta view into the frozen order, so unmerged inserts appear immediately
+and deleted keys never scan — point and range reads agree on every epoch.
 
 The free functions in :mod:`repro.core.tensor_index` remain supported as
 the kernel-level seam underneath this facade (legacy surface — see the
@@ -56,7 +53,6 @@ from repro.core.tensor_index import (
     lookup_values,
     merge_delta,
     pad_queries,
-    resolve_search_backend,
     scan_batch,
     _search_batch_jit,
 )
@@ -69,34 +65,25 @@ from .snapshot import load_index, save_index
 
 @dataclasses.dataclass(frozen=True)
 class IndexConfig:
-    """All index policy in one place; env vars are defaults, not the API.
-
-    Resolution precedence (DESIGN.md §8): explicit config field > environment
-    variable > built-in default.  ``search_backend=None`` defers to
-    ``REPRO_SEARCH_BACKEND`` (default ``"jnp"``); ``kernel_backend=None``
-    defers to ``REPRO_KERNEL_BACKEND`` (default: interpret off-TPU).
-    """
+    """All index policy in one place (DESIGN.md §8)."""
 
     width: Optional[int] = None          # None: longest bulk-load key + headroom
     delta_capacity: int = 4096           # delta-buffer entry pool size
     delta_bytes: Optional[int] = None    # delta byte pool (None: capacity-derived)
     delta_probes: int = 16               # open-addressing probe bound
-    search_backend: Optional[str] = None  # "jnp" | "pallas" | None(env)
-    kernel_backend: Optional[str] = None  # "auto" | "interpret" | "native" | None(env)
+    # accepts only None or "jnp": there is one traversal.  Kept because the
+    # on-chip benchmark's config files still pass "search_backend": "jnp";
+    # it goes once they drop the key.
+    search_backend: Optional[str] = None
     auto_merge_threshold: Optional[float] = 0.75  # None disables auto-compaction
     scan_window: int = 16                # default ScanRequest window
     builder: Optional[LITSConfig] = None  # host build policy (cnode cap, HPT shape)
 
-    def resolved_search_backend(self) -> str:
-        return resolve_search_backend(self.search_backend)
-
-    def resolved_interpret(self) -> Optional[bool]:
-        """Pallas execution mode: None defers to the process-wide env default."""
-        if self.kernel_backend is None:
-            return None
-        from repro.kernels.ops import resolve_interpret
-
-        return resolve_interpret(self.kernel_backend)
+    def __post_init__(self):
+        if self.search_backend not in (None, "jnp"):
+            raise ValueError(
+                f"search_backend={self.search_backend!r}: the only traversal "
+                "is 'jnp' (or leave it unset)")
 
 
 # ---------------------------------------------------------------------------
@@ -270,11 +257,11 @@ class StringIndexBase:
 
     config: IndexConfig
     # device->host syncs on the request path (one per get, put, delete or
-    # scan group); backends that do not count them read 0
+    # scan group); an implementation that does not count them reads 0
     host_syncs: int = 0
     # search-walk loop iterations over all get groups, and those of them
     # that ran the model-node step (read in the get group's one sync);
-    # None on backends that do not count them
+    # None where not counted (the mesh index)
     walk_iters: Optional[int] = None
     model_step_iters: Optional[int] = None
     # terminal-resolve compare rounds over all get groups (one per group
@@ -305,18 +292,18 @@ class StringIndexBase:
 class StringIndex(StringIndexBase):
     """Single-device LITS over the HPT + sub-trie + PMSS hybrid (PAPER.md §3–§5)."""
 
+    walk_iters: int
+    model_step_iters: int
+    resolve_rounds: int
+
     def __init__(self, builder: Optional[LITSBuilder], ti: TensorIndex,
                  config: IndexConfig):
         self._builder = builder        # None after load(): rebuilt lazily on merge
         self.ti = ti
         self.config = config
-        self._backend = config.resolved_search_backend()
-        self._interpret = config.resolved_interpret()
         self.merge_count = 0
         self.host_syncs = 0
-        # the fused Pallas kernel does not count its walk or resolve
-        self.walk_iters = self.model_step_iters = self.resolve_rounds = (
-            0 if self._backend == "jnp" else None)
+        self.walk_iters = self.model_step_iters = self.resolve_rounds = 0
         self._host_pool = None         # lazy (key_bytes, ent_off, ent_len) copies
         # None = no merge in flight; a list = the epoch-merge journal: every
         # mutation applied between begin_merge() and commit_merge() is
@@ -370,7 +357,7 @@ class StringIndex(StringIndexBase):
     def load(cls, path: str,
              config: Optional[IndexConfig] = None) -> "StringIndex":
         """Restore a snapshot.  ``config`` supplies *runtime* policy only
-        (backends, merge threshold, scan window); the structural parameters
+        (merge threshold, scan window); the structural parameters
         (width, delta sizing) come from the snapshot itself."""
         ti = load_index(path)
         return cls(None, ti, config or IndexConfig())
@@ -430,17 +417,16 @@ class StringIndex(StringIndexBase):
             qb, ql = jnp.asarray(qb), jnp.asarray(ql)
         with TraceAnnotation("lits.index.get.dispatch"):
             found, eid, isd, iters, model_iters, rounds = _search_batch_jit(
-                self.ti, qb, ql, self._backend, self._interpret)
+                self.ti, qb, ql)
             lo, hi = lookup_values(self.ti, eid, isd)
         # ONE host sync for the whole get group
         with TraceAnnotation("lits.index.get.sync"):
             found, lo, hi, iters, model_iters, rounds = jax.device_get(
                 (found, lo, hi, iters, model_iters, rounds))
         self.host_syncs += 1
-        if iters is not None:
-            self.walk_iters += int(iters)
-            self.model_step_iters += int(model_iters)
-            self.resolve_rounds += int(rounds)
+        self.walk_iters += int(iters)
+        self.model_step_iters += int(model_iters)
+        self.resolve_rounds += int(rounds)
         with TraceAnnotation("lits.index.get.decode"):
             return found, np.where(found, _join_values(lo, hi), 0)
 
@@ -521,9 +507,7 @@ class StringIndex(StringIndexBase):
         index the base pools where ``~is_delta`` and the delta pools where
         ``is_delta`` (the ``lookup_values`` contract)."""
         qb, ql = pad_queries(list(starts), self.ti.width)
-        return scan_batch(self.ti, jnp.asarray(qb), jnp.asarray(ql),
-                          window, backend=self._backend,
-                          interpret=self._interpret)
+        return scan_batch(self.ti, jnp.asarray(qb), jnp.asarray(ql), window)
 
     # -- single-op conveniences --------------------------------------------
 

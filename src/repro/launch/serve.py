@@ -11,7 +11,6 @@ import time
 import jax
 import numpy as np
 
-from repro.configs.base import IndexRuntimeConfig
 from repro.configs.registry import get_arch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LMModel
@@ -44,9 +43,7 @@ def main() -> None:
         raise SystemExit(f"{cfg.name} is encoder-only: no decode path")
     model = LMModel(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    runtime = IndexRuntimeConfig.from_env().validate()
-    eng = ServeEngine(model, params, index_backend=runtime.search_backend,
-                      cache_capacity=args.cache_capacity,
+    eng = ServeEngine(model, params, cache_capacity=args.cache_capacity,
                       max_len=args.max_len)
     rng = np.random.default_rng(0)
     base = rng.integers(0, cfg.vocab, size=(args.batch, args.prompt_len)).astype(np.int32)

@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -25,19 +25,15 @@ class ServeEngine:
     """Greedy batched decoding with exact-prefix KV reuse via LITS."""
 
     def __init__(self, model: LMModel, params, cache_capacity: int = 1024,
-                 index_backend: Optional[str] = None,
                  index_config=None, max_len: int = 512,
                  index_service=None):
         self.model = model
         self.params = params
         # index_config: a repro.index.IndexConfig for the prompt cache
-        # (unified policy, DESIGN.md §8).  index_backend is the legacy
-        # shorthand for just the traversal backend ("jnp" | "pallas" |
-        # None -> REPRO_SEARCH_BACKEND); ignored when index_config is given.
+        # (unified policy, DESIGN.md §8).
         # index_service: a repro.serve.service.IndexService to share one
         # request plane across engines (DESIGN.md §9).
         self.prefix_cache = PrefixCache(capacity=cache_capacity,
-                                        backend=index_backend,
                                         config=index_config,
                                         service=index_service)
         self.prefill_fn = jax.jit(model.prefill, static_argnames=("max_len",))

@@ -54,12 +54,11 @@ class PrefixCache:
     _ids = itertools.count()
 
     def __init__(self, capacity: int = 4096, width: int = 256, seed_keys=None,
-                 backend: Optional[str] = None,
                  config: Optional[IndexConfig] = None,
                  service: Optional[IndexService] = None,
                  service_config: Optional[ServiceConfig] = None):
         # `config` is the unified index policy object; the legacy kwargs
-        # (capacity/width/backend) are defaults folded into it.  `service`
+        # (capacity/width) are defaults folded into it.  `service`
         # lets several caches/engines share one request plane (the cache
         # does not own a passed-in service and close() won't stop it).
         self._owns_service = service is None
@@ -73,8 +72,7 @@ class PrefixCache:
         if service is None:
             if config is None:
                 config = IndexConfig(width=width,
-                                     delta_capacity=max(64, capacity),
-                                     search_backend=backend)
+                                     delta_capacity=max(64, capacity))
             seed = seed_keys or [b"\x01<prefix-cache-sentinel>"]
             index = StringIndex.bulk_load(seed, config=config)
             service = IndexService(index, service_config or ServiceConfig())

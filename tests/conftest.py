@@ -22,3 +22,22 @@ def pytest_configure(config):
 @pytest.fixture
 def rng():
     return np.random.default_rng(12345)
+
+
+@pytest.fixture
+def as_form(tmp_path):
+    """``as_form(index, form)``: a ``StringIndex`` as ``"built"`` (returned
+    unchanged) or ``"loaded"``: saved and read back with ``StringIndex.load``,
+    the form the on-chip benchmark serves (a loaded index's device arrays
+    come from the snapshot, and it has no host builder until a merge)."""
+    from repro.index import StringIndex
+
+    def convert(index, form):
+        if form == "built":
+            return index
+        assert form == "loaded", form
+        path = str(tmp_path / f"as-form-{id(index)}.npz")
+        index.save(path)
+        return StringIndex.load(path, index.config)
+
+    return convert

@@ -1,6 +1,5 @@
 """Compile-only guards for the TPU v5e: the main-path programs at the sizes
-``chip_smoke.py`` runs, the routed mesh service on a 2x2 mesh, and today's
-Mosaic verdict on the fused traversal kernel.
+``chip_smoke.py`` runs, and the routed mesh service on a 2x2 mesh.
 
 Nothing here runs on a chip: each test compiles for a *described* v5e
 topology, which the installed TPU compiler accepts without one attached.
@@ -19,7 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core.tensor_index import (
     STATIC_FIELDS, TensorIndex, _scan_batch_jit, _search_batch_jit,
-    delete_batch, insert_batch, lookup_values,
+    delete_batch, insert_batch, lookup_values, rank_batch,
 )
 from repro.data.synthetic import load
 from repro.index import IndexConfig, StringIndex
@@ -89,8 +88,7 @@ def _hlo(lowered):
 
 def test_search_and_lookup_compile_for_v5e(chip_index, one_chip):
     qb, ql = _queries(GETS, chip_index.width, one_chip)
-    assert _hlo(_search_batch_jit.lower(chip_index, qb, ql,
-                                        backend="jnp", interpret=None))
+    assert _hlo(_search_batch_jit.lower(chip_index, qb, ql))
     eid = jax.ShapeDtypeStruct((GETS,), jnp.int32, sharding=one_chip)
     isd = jax.ShapeDtypeStruct((GETS,), jnp.bool_, sharding=one_chip)
     assert _hlo(lookup_values.lower(chip_index, eid, isd))
@@ -98,8 +96,7 @@ def test_search_and_lookup_compile_for_v5e(chip_index, one_chip):
 
 def test_scan_compiles_for_v5e(chip_index, one_chip):
     qb, ql = _queries(SCANS, chip_index.width, one_chip)
-    assert _hlo(_scan_batch_jit.lower(chip_index, qb, ql, window=WINDOW,
-                                      backend="jnp", interpret=None))
+    assert _hlo(_scan_batch_jit.lower(chip_index, qb, ql, window=WINDOW))
 
 
 @pytest.mark.parametrize("op", ["insert", "delete"])
@@ -125,19 +122,13 @@ def test_routed_service_compiles_on_2x2_mesh(topo):
     mesh = make_mesh((4,), ("data",), devices=topo.devices[:4])
     stacked = _abstract(sidx.stacked, NamedSharding(mesh, P("data")))
     fn = make_service_fn(dataclasses.replace(sidx, stacked=stacked), mesh,
-                         per_dest_capacity=GETS // 4, backend="jnp")
+                         per_dest_capacity=GETS // 4)
     qb, ql = _queries(GETS, sidx.width, NamedSharding(mesh, P("data")))
     assert "all-to-all" in _hlo(fn.lower(stacked, qb, ql))
 
 
-def test_fused_traversal_kernel_is_refused_by_mosaic(small_index, one_chip):
-    """Today's verdict: the fused kernels index 1-D VMEM tables with vector
-    indices, which Mosaic refuses.  A change that makes the kernel compile
-    must turn this into a positive test."""
-    from repro.kernels import ops
-
-    ti = _abstract(small_index.ti, one_chip)
-    qb, ql = _queries(256, ti.width, one_chip)
-    fn = jax.jit(lambda t, b, l: ops.fused_search(t, b, l, interpret=False))
-    with pytest.raises(NotImplementedError, match="2D gather"):
-        fn.lower(ti, qb, ql).compile()
+def test_rank_compiles_for_v5e(chip_index, one_chip):
+    """The ordered rank alone (``rank_batch``): a binary search of
+    ``rank_iters`` steps over the 1M-key sorted entry order."""
+    qb, ql = _queries(SCANS, chip_index.width, one_chip)
+    assert "while" in _hlo(rank_batch.lower(chip_index, qb, ql))

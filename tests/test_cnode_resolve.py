@@ -9,8 +9,8 @@ cnode and check that every key still resolves exactly:
   earlier slot with the same hash;
 * an absent key with the same hash as stored slots misses, and so does an
   over-width query whose first ``width`` bytes hash like them;
-* the jnp walk and the fused Pallas kernel (interpret mode) agree bit for
-  bit on ``(found, eid)``;
+* the same holds on the index after ``save`` and ``StringIndex.load``,
+  with the entry ids of the built index;
 * ``StringIndex.resolve_rounds`` counts one round for a batch with no
   collision and more where a lane passes an equal-hash slot.
 """
@@ -94,17 +94,19 @@ def test_every_colliding_key_finds_its_own_value(c):
     assert not {c.absent, over, c.unhashed} & set(c.ref)
 
 
-def test_jnp_and_pallas_agree_under_collisions(c):
+def test_collisions_resolve_on_a_loaded_index(c, as_form):
     queries = (c.stored[::-1] + [c.absent, c.stored[1] + b"xyz", c.unhashed]
                + c.other + c.background[::7])
-    qb, ql = pad_queries(queries, c.idx.ti.width)
+    loaded = as_form(c.idx, "loaded")
+    qb, ql = pad_queries(queries, loaded.ti.width)
     qb, ql = jnp.asarray(qb), jnp.asarray(ql)
-    f_j, e_j, _ = search_batch(c.idx.ti, qb, ql, backend="jnp")
-    f_p, e_p, _ = search_batch(c.idx.ti, qb, ql, backend="pallas",
-                               interpret=True)
-    assert (np.asarray(f_j) == np.asarray(f_p)).all()
-    assert (np.asarray(e_j) == np.asarray(e_p)).all()
-    assert np.asarray(f_j).tolist() == [q in c.ref for q in queries]
+    f_b, e_b, _ = search_batch(c.idx.ti, qb, ql)
+    f_l, e_l, _ = search_batch(loaded.ti, qb, ql)
+    assert np.asarray(f_l).tolist() == [q in c.ref for q in queries]
+    assert (np.asarray(e_l) == np.asarray(e_b)).all()
+    assert (np.asarray(f_l) == np.asarray(f_b)).all()
+    found, vals = loaded.get_batch(queries)
+    assert vals.tolist() == [c.ref.get(q, 0) for q in queries]
 
 
 @pytest.mark.parametrize("pick,rounds", [

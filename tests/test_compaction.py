@@ -4,7 +4,8 @@ The acceptance contract (ISSUE 4):
 
 * the vectorized ``merge_delta`` replay (bulk ``insert_many``/``delete_many``
   + partial refreeze off the builder's incremental caches) is bit-identical
-  to a sequential oracle, on BOTH traversal backends;
+  to a sequential oracle, on a built index and on one loaded from a
+  snapshot (whose first merge rebuilds the host builder);
 * device-side in-place base value updates survive the merge (they replay
   into the builder via the val-sync seam — previously they silently
   reverted);
@@ -46,15 +47,15 @@ def _check_oracle(index: StringIndex, oracle: dict) -> None:
     assert [v for _, v in scanned] == [oracle[k] for k in live]
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_vectorized_merge_bit_identical_to_sequential_oracle(rng, backend):
+@pytest.mark.parametrize("form", ["built", "loaded"])
+def test_vectorized_merge_bit_identical_to_sequential_oracle(rng, form,
+                                                            as_form):
     """Mixed fresh puts / base updates / deletes / resurrects across TWO merge
     cycles (the second exercises the warm incremental caches) match a plain
-    sequential dict oracle, on both traversal backends."""
+    sequential dict oracle, on a built and on a loaded index."""
     keys, vals = _corpus(rng, 400)
-    cfg = IndexConfig(delta_capacity=1024, auto_merge_threshold=None,
-                      search_backend=backend)
-    index = StringIndex.bulk_load(keys, vals, cfg)
+    cfg = IndexConfig(delta_capacity=1024, auto_merge_threshold=None)
+    index = as_form(StringIndex.bulk_load(keys, vals, cfg), form)
     oracle = {k: int(v) for k, v in zip(keys, vals)}
 
     def apply(batch):
@@ -99,19 +100,20 @@ def test_base_value_update_survives_merge(rng):
     assert index.get(keys[8]) == 848484 and index.get(keys[7]) == 424242
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_concurrent_writers_race_forced_compaction(rng, backend):
+@pytest.mark.parametrize("form", ["built", "loaded"])
+def test_concurrent_writers_race_forced_compaction(rng, form, as_form):
     """Writer threads + forced ``compact()`` racing on the service: merges
     run off-lock mid-traffic (epoch swap + journal re-drain), and the final
     index is bit-identical to the sequential per-thread oracle.  Disjoint
     per-writer keyspaces make the oracle interleaving-independent."""
     keys, vals = _corpus(rng, 300)
-    svc = IndexService.bulk_load(
-        {"t": (keys, vals)},
-        IndexConfig(delta_capacity=8192, auto_merge_threshold=None,
-                    search_backend=backend),
-        ServiceConfig(max_batch=64, max_delay_ms=0.5, default_tenant="t",
-                      merge_threshold=None))
+    index = StringIndex.bulk_load(
+        [IndexService.encode_key("t", k) for k in keys], vals,
+        IndexConfig(delta_capacity=8192, auto_merge_threshold=None))
+    svc = IndexService(as_form(index, form),
+                       ServiceConfig(max_batch=64, max_delay_ms=0.5,
+                                     default_tenant="t",
+                                     merge_threshold=None))
     n_writers, rounds = 4, 6
     oracle = {k: int(v) for k, v in zip(keys, vals)}
     barrier = threading.Barrier(n_writers + 1)

@@ -4,8 +4,9 @@ admission control, maintenance (DESIGN.md §9).
 The acceptance contract (ISSUE 3):
 
 * ops coalesced across >= 8 concurrent logical clients resolve bit-identical
-  to a direct ``StringIndex.execute`` of the same ops, on BOTH traversal
-  backends, and on the distributed backend for its supported op set;
+  to a direct ``StringIndex.execute`` of the same ops, with the service on
+  a built and on a loaded index, and on the distributed backend for its
+  supported op set;
 * tenants are isolated: cross-tenant gets miss, scans never leak another
   tenant's keys and return tenant-local (stripped) keys;
 * cursor pagination concatenates to exactly the one-shot scan;
@@ -33,16 +34,18 @@ def _corpus(rng, n=600):
     return keys, vals
 
 
-def _twins(keys, vals, backend, tenant="t0", **svc_kw):
-    """(service, direct) over identical bulk loads of tenant-encoded keys."""
+def _twins(keys, vals, tenant="t0", index_form=lambda index: index,
+           **svc_kw):
+    """(service, direct) over identical bulk loads of tenant-encoded keys;
+    ``index_form`` maps the service's index (e.g. to a loaded copy)."""
     cfg = IndexConfig(delta_capacity=4096, auto_merge_threshold=None,
-                      search_backend=backend, scan_window=6)
+                      scan_window=6)
     enc = [IndexService.encode_key(tenant, k) for k in keys]
     direct = StringIndex.bulk_load(enc, vals, cfg)
     kw = dict(max_batch=4096, max_delay_ms=25.0, merge_threshold=None,
               default_tenant=tenant)
     kw.update(svc_kw)
-    svc = IndexService(StringIndex.bulk_load(enc, vals, cfg),
+    svc = IndexService(index_form(StringIndex.bulk_load(enc, vals, cfg)),
                        ServiceConfig(**kw))
     return svc, direct
 
@@ -59,12 +62,13 @@ def _same_result(got, want, tenant):
         assert got.entries == _strip(tenant, want.entries)
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_single_flush_bit_identical_to_direct_execute(rng, backend):
+@pytest.mark.parametrize("form", ["built", "loaded"])
+def test_single_flush_bit_identical_to_direct_execute(rng, form, as_form):
     """One coalesced flush of a mixed GET/PUT/SCAN/DELETE batch == one direct
     facade ``execute`` of the same batch, op for op, bit for bit."""
     keys, vals = _corpus(rng)
-    svc, direct = _twins(keys, vals, backend)
+    svc, direct = _twins(keys, vals,
+                         index_form=lambda index: as_form(index, form))
     batch = (
         [GetRequest(k) for k in keys[:30]]
         + [GetRequest(k + b"~miss") for k in keys[:5]]
@@ -91,13 +95,14 @@ def test_single_flush_bit_identical_to_direct_execute(rng, backend):
     svc.close()
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_concurrent_clients_coalesced_and_bit_identical(rng, backend):
+@pytest.mark.parametrize("form", ["built", "loaded"])
+def test_concurrent_clients_coalesced_and_bit_identical(rng, form, as_form):
     """>= 8 logical clients with disjoint keyspaces submit concurrently; the
     coalescer folds them into shared dispatches (coalescing factor > 1) and
     every client's results match a direct facade run of its ops."""
     keys, vals = _corpus(rng, 800)
-    svc, direct = _twins(keys, vals, backend, max_batch=64)
+    svc, direct = _twins(keys, vals, max_batch=64,
+                         index_form=lambda index: as_form(index, form))
     n_clients = 8
 
     def client_ops(i):

@@ -24,17 +24,21 @@ from repro.serve.service import IndexService, ServiceConfig
 BASE = [b"k-%03d" % i for i in range(0, 40, 2)]      # even keys frozen
 
 
-def _index(backend, keys=BASE, **cfg_kw):
-    cfg = IndexConfig(width=16, delta_capacity=64,
-                      auto_merge_threshold=None, search_backend=backend,
-                      **cfg_kw)
+def _index(keys=BASE):
+    cfg = IndexConfig(width=16, delta_capacity=64, auto_merge_threshold=None)
     vals = np.arange(len(keys), dtype=np.int64) * 10 + 1
     return StringIndex.bulk_load(keys, vals, cfg)
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_tombstone_shadowing_mid_window(backend):
-    index = _index(backend)
+@pytest.fixture(params=["built", "loaded"])
+def make_index(request, as_form):
+    """``make_index(keys=BASE)``: :func:`_index`, as built or as loaded
+    from a snapshot."""
+    return lambda keys=BASE: as_form(_index(keys), request.param)
+
+
+def test_tombstone_shadowing_mid_window(make_index):
+    index = make_index()
     index.execute([DeleteRequest(b"k-006"), DeleteRequest(b"k-010")])
     got = [k for k, _ in index.scan(b"k-004", 5)]
     # the window slides PAST the two tombstoned keys to later live keys
@@ -46,9 +50,8 @@ def test_tombstone_shadowing_mid_window(backend):
     assert got == [b"k-008", b"k-012", b"k-014"]
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_put_resurrect_then_scan(backend):
-    index = _index(backend)
+def test_put_resurrect_then_scan(make_index):
+    index = make_index()
     index.execute([DeleteRequest(b"k-008")])
     assert [k for k, _ in index.scan(b"k-006", 3)] == \
         [b"k-006", b"k-010", b"k-012"]
@@ -66,9 +69,8 @@ def test_put_resurrect_then_scan(backend):
     assert dict(got)[b"k-008"] == 777
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_window_straddles_base_delta_seam(backend):
-    index = _index(backend)
+def test_window_straddles_base_delta_seam(make_index):
+    index = make_index()
     odd = [b"k-%03d" % i for i in range(1, 21, 2)]   # interleaves the base
     index.execute([PutRequest(k, 5000 + i) for i, k in enumerate(odd)])
     got = [k for k, _ in index.scan(b"k-003", 8)]
@@ -82,11 +84,10 @@ def test_window_straddles_base_delta_seam(backend):
     assert got[b"k-003"] == 5001 and got[b"k-004"] == 21
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_delta_only_index_scans(backend):
+def test_delta_only_index_scans(make_index):
     """ISSUE 5 satellite: an EMPTY base with a live delta must scan — the
     old ``root_item != 0`` guard masked every window to nothing."""
-    index = _index(backend, keys=[])
+    index = make_index(keys=[])
     assert index.n_entries <= 1  # only the freeze pad sentinel
     res = index.execute([ScanRequest(b"", 8)])
     assert res.results[0].entries == ()   # truly empty index: empty scan
@@ -101,9 +102,8 @@ def test_delta_only_index_scans(backend):
     assert [k for k, _ in index.scan(b"", 8)] == [b"x-1", b"x-3"]
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_scan_after_emptying_delta_only_index(backend):
-    index = _index(backend, keys=[])
+def test_scan_after_emptying_delta_only_index(make_index):
+    index = make_index(keys=[])
     index.execute([PutRequest(b"solo", 9)])
     index.execute([DeleteRequest(b"solo")])
     assert index.scan(b"", 4) == []
@@ -185,7 +185,7 @@ def test_pre_v4_snapshot_recomputes_sorted_delta_view(tmp_path):
     scans see the snapshot's unmerged state."""
     import json
 
-    index = _index("jnp")
+    index = _index()
     index.execute([PutRequest(b"k-007", 7), PutRequest(b"k-033", 3),
                    DeleteRequest(b"k-012")])
     want = index.scan(b"k-004", 8)
@@ -205,11 +205,10 @@ def test_pre_v4_snapshot_recomputes_sorted_delta_view(tmp_path):
     assert dict(want)[b"k-007"] == 7 and b"k-012" not in dict(want)
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_gets_and_scans_agree_every_epoch(backend):
+def test_gets_and_scans_agree_every_epoch(make_index):
     """Read-your-writes coherence: at no point may a key be gettable but
     unscannable or vice versa (the exact gap this PR closes)."""
-    index = _index(backend)
+    index = make_index()
     index.execute([PutRequest(b"k-001", 1), DeleteRequest(b"k-004"),
                    PutRequest(b"k-033", 3), DeleteRequest(b"k-033")])
     for _ in range(2):

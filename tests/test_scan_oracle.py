@@ -1,13 +1,13 @@
 """Property-based differential oracle suite for delta-aware scans (ISSUE 5).
 
 Generated put/get/delete/scan/compact sequences run against a host
-``dict`` + sorted-list oracle, on BOTH traversal backends, across many
-merge epochs.  Keys come from a skewed-prefix generator (heavy shared
+``dict`` + sorted-list oracle, on a built index and on one loaded from a
+snapshot, across many merge epochs.  Keys come from a skewed-prefix generator (heavy shared
 prefixes — the paper's hard case — plus a uniform tail), so scan windows
 constantly straddle the base/delta seam, tombstone shadows and resurrected
 keys.
 
-Design note: sequences share one long-lived index per backend (state
+Design note: sequences share one long-lived index per form (state
 carries over, like a soak test) instead of rebuilding per sequence — a
 fresh bulk load per sequence would give every sequence novel pool shapes
 and pay an XLA compile per op kind per sequence.  The oracle is exact
@@ -21,6 +21,9 @@ only have the seeded-sampling fallback shim — tests/_hypothesis_fallback);
 the deterministic sweep below guarantees >= 200 generated sequences run
 regardless of which hypothesis implementation is present.
 """
+import os
+import tempfile
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -56,15 +59,21 @@ def _oracle_scan(oracle: dict, start: bytes, window: int):
 
 
 class _Driver:
-    """One long-lived (index, oracle) pair per backend."""
+    """One long-lived (index, oracle) pair per form: ``"built"``, or
+    ``"loaded"`` (saved and read back with ``StringIndex.load``)."""
 
-    def __init__(self, backend: str):
+    def __init__(self, form: str):
         rng = np.random.default_rng(0xC0FFEE)
         base = sorted({_rand_key(rng) for _ in range(120)})
         vals = rng.integers(0, 1 << 40, len(base)).astype(np.int64)
         cfg = IndexConfig(width=WIDTH, delta_capacity=256,
-                          auto_merge_threshold=None, search_backend=backend)
+                          auto_merge_threshold=None)
         self.index = StringIndex.bulk_load(base, vals, cfg)
+        if form == "loaded":
+            with tempfile.TemporaryDirectory() as d:
+                path = os.path.join(d, "index.npz")
+                self.index.save(path)
+                self.index = StringIndex.load(path, cfg)
         self.oracle = dict(zip(base, vals.tolist()))
         self.epochs_seen = {self.index.epoch}
         self.sequences = 0
@@ -136,21 +145,21 @@ class _Driver:
 _DRIVERS = {}
 
 
-def _driver(backend: str) -> _Driver:
-    if backend not in _DRIVERS:
-        _DRIVERS[backend] = _Driver(backend)
-    return _DRIVERS[backend]
+def _driver(form: str) -> _Driver:
+    if form not in _DRIVERS:
+        _DRIVERS[form] = _Driver(form)
+    return _DRIVERS[form]
 
 
-# 130 jnp + 80 pallas = 210 generated sequences >= the 200 the acceptance
-# criteria require, split so the slower interpreted-kernel leg stays cheap
-_N_SEQ = {"jnp": 130, "pallas": 80}
+# 130 built + 80 loaded = 210 generated sequences >= the 200 the acceptance
+# criteria require
+_N_SEQ = {"built": 130, "loaded": 80}
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_scan_oracle_generated_sequences(backend):
-    drv = _driver(backend)
-    n = _N_SEQ[backend]
+@pytest.mark.parametrize("form", ["built", "loaded"])
+def test_scan_oracle_generated_sequences(form):
+    drv = _driver(form)
+    n = _N_SEQ[form]
     for s in range(n):
         drv.run_sequence(seed=0x5EED + 7919 * s)
         if (s + 1) % 25 == 0:
@@ -167,19 +176,19 @@ def test_scan_oracle_generated_sequences(backend):
 def test_scan_oracle_hypothesis(seed):
     """Hypothesis-driven entry point over the same differential driver
     (real hypothesis shrinks seeds on failure; the fallback shim samples
-    them) — one drawn seed = one generated sequence on each backend."""
-    for backend in ("jnp", "pallas"):
-        _driver(backend).run_sequence(seed)
+    them) — one drawn seed = one generated sequence on each form."""
+    for form in ("built", "loaded"):
+        _driver(form).run_sequence(seed)
 
 
 def test_scan_oracle_post_epoch_consistency():
-    """After everything, force one more merge on each backend and require
+    """After everything, force one more merge on each form and require
     the fully-compacted view to equal the oracle (tombstones physically
     reconciled, resurrects preserved)."""
-    for backend in ("jnp", "pallas"):
-        if backend not in _DRIVERS:
+    for form in ("built", "loaded"):
+        if form not in _DRIVERS:
             continue
-        drv = _DRIVERS[backend]
+        drv = _DRIVERS[form]
         drv.merge()
         assert drv.index.delta_fill == 0.0
         drv.full_sweep()

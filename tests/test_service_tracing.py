@@ -8,7 +8,9 @@
 * ``ServiceStats.walk_iters`` and ``model_step_iters`` mirror the search
   walk's iteration counts, read in the get group's one sync;
 * ``ServiceStats.resolve_rounds`` mirrors the terminal resolve's compare
-  rounds, read in the same sync, and is None on the ``pallas`` backend;
+  rounds, read in the same sync, on a built and on a loaded index; the
+  three walk and resolve counters are None on an index that does not
+  count them;
 * ``ServiceStats.queue_wait_ms_total`` sees a flusher held by its index,
   ``flush_ms_total`` the hold itself, and ``reset_stats`` zeroes them.
 """
@@ -33,15 +35,16 @@ def _corpus(rng, n=200):
     return keys, np.arange(len(keys), dtype=np.int64) * 3 + 1
 
 
-def _service(rng, index=None, **kw):
+def _service(rng, index_form=lambda index: index, **kw):
+    """A service over tenant ``t``'s corpus; ``index_form`` maps the
+    bulk-loaded index (e.g. to a loaded copy)."""
     keys, vals = _corpus(rng)
     cfg = dict(max_batch=1024, default_tenant="t", merge_threshold=None)
     cfg.update(kw)
-    svc = IndexService.bulk_load(
-        {"t": (keys, vals)},
-        IndexConfig(auto_merge_threshold=None, **(index or {})),
-        ServiceConfig(**cfg))
-    return svc, keys
+    index = StringIndex.bulk_load(
+        [IndexService.encode_key("t", k) for k in keys], vals,
+        IndexConfig(auto_merge_threshold=None))
+    return IndexService(index_form(index), ServiceConfig(**cfg)), keys
 
 
 def _lines(xplane_path):
@@ -161,7 +164,7 @@ def test_queue_wait_counts_a_held_flusher():
     assert wait_ms / queued >= hold_s * 1e3
     assert s1.mean_queue_wait_ms >= hold_s * 1e3 * queued / (queued + 1)
     assert s1.flush_ms_total >= hold_s * 1e3   # the held flush
-    assert s1.host_syncs == 0          # a backend that does not count them
+    assert s1.host_syncs == 0          # an index that does not count them
     assert s1.walk_iters is None and s1.model_step_iters is None
     assert s1.resolve_rounds is None
 
@@ -194,10 +197,9 @@ def test_walk_counters_ride_the_get_sync(rng):
     assert s.walk_iters >= s.model_step_iters >= 1
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_resolve_rounds_ride_the_get_sync(rng, backend):
-    svc, keys = _service(rng, index=dict(search_backend=backend,
-                                         kernel_backend="interpret"))
+@pytest.mark.parametrize("form", ["built", "loaded"])
+def test_resolve_rounds_ride_the_get_sync(rng, form, as_form):
+    svc, keys = _service(rng, lambda index: as_form(index, form))
     idx = svc.index
 
     def gets(n):
@@ -214,9 +216,6 @@ def test_resolve_rounds_ride_the_get_sync(rng, backend):
     s = svc.stats()
     svc.close()
     assert s.flushes == 3 and s.syncs_per_flush == 1.0
-    if backend == "pallas":
-        assert s.resolve_rounds is None and idx.resolve_rounds is None
-    else:
-        # every group holds a hit, so it resolves in one round or more
-        assert s.resolve_rounds == idx.resolve_rounds - at_reset
-        assert s.resolve_rounds >= s.host_syncs == 3
+    # every group holds a hit, so it resolves in one round or more
+    assert s.resolve_rounds == idx.resolve_rounds - at_reset
+    assert s.resolve_rounds >= s.host_syncs == 3

@@ -3,8 +3,8 @@
 The acceptance contract (ISSUE 2 / DESIGN.md §8):
 
 * ``execute`` on a mixed GET/PUT/SCAN batch is bit-identical to the
-  equivalent sequence of legacy free-function calls, on BOTH traversal
-  backends;
+  equivalent sequence of legacy free-function calls, on a built index and
+  on one loaded from a snapshot;
 * failures (over-width keys, full delta pool) surface as per-op Status
   codes, never exceptions;
 * puts past the delta threshold trigger ``merge_delta`` automatically and
@@ -76,12 +76,12 @@ def _any_key(ti, eid: int, is_delta: bool) -> bytes:
     return np.asarray(ti.db_bytes[off: off + ln]).tobytes()
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_execute_bit_identical_to_legacy(rng, backend):
+@pytest.mark.parametrize("form", ["built", "loaded"])
+def test_execute_bit_identical_to_legacy(rng, form, as_form):
     keys, vals = _corpus(rng)
     cfg = IndexConfig(delta_capacity=512, auto_merge_threshold=None,
-                      search_backend=backend, scan_window=9)
-    index = StringIndex.bulk_load(keys, vals, cfg)
+                      scan_window=9)
+    index = as_form(StringIndex.bulk_load(keys, vals, cfg), form)
     legacy = StringIndex.bulk_load(keys, vals, cfg)  # identical twin lineage
 
     batch = (
@@ -163,11 +163,11 @@ def test_auto_merge_regression(rng):
         [b"zm-%03d" % i for i in range(12)]
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_save_load_roundtrip_bit_identical(rng, tmp_path, backend):
+@pytest.mark.parametrize("form", ["built", "loaded"])
+def test_save_load_roundtrip_bit_identical(rng, tmp_path, form, as_form):
     keys, vals = _corpus(rng, 400)
-    index = StringIndex.bulk_load(keys, vals, IndexConfig(
-        delta_capacity=128, auto_merge_threshold=None))
+    index = as_form(StringIndex.bulk_load(keys, vals, IndexConfig(
+        delta_capacity=128, auto_merge_threshold=None)), form)
     # live delta state rides into the snapshot too
     index.execute([PutRequest(b"dl-%03d" % i, i) for i in range(30)])
     path = tmp_path / "idx.snap"
@@ -177,13 +177,13 @@ def test_save_load_roundtrip_bit_identical(rng, tmp_path, backend):
     probe = keys[::7] + [b"dl-%03d" % i for i in range(30)] + [b"nope-1", b"nope-2"]
     qb, ql = pad_queries(probe, index.ti.width)
     qb, ql = jnp.asarray(qb), jnp.asarray(ql)
-    f0, e0, d0 = search_batch(index.ti, qb, ql, backend=backend)
-    f1, e1, d1 = search_batch(restored.ti, qb, ql, backend=backend)
+    f0, e0, d0 = search_batch(index.ti, qb, ql)
+    f1, e1, d1 = search_batch(restored.ti, qb, ql)
     assert (np.asarray(f0) == np.asarray(f1)).all()
     assert (np.asarray(e0) == np.asarray(e1)).all()
     assert (np.asarray(d0) == np.asarray(d1)).all()
-    r0 = rank_batch(index.ti, qb, ql, backend=backend)
-    r1 = rank_batch(restored.ti, qb, ql, backend=backend)
+    r0 = rank_batch(index.ti, qb, ql)
+    r1 = rank_batch(restored.ti, qb, ql)
     assert (np.asarray(r0) == np.asarray(r1)).all()
 
 
@@ -233,24 +233,21 @@ def test_update_of_first_entry_survives_a_mixed_batch():
 
 
 def test_config_beats_env(rng, monkeypatch):
-    """Config precedence: explicit field > env var > default (DESIGN.md §8)."""
+    """The config is the one source of index policy (DESIGN.md §8): the env
+    vars that once picked a traversal engine are not read, and the explicit
+    fields reach the index."""
     monkeypatch.setenv("REPRO_SEARCH_BACKEND", "pallas")
-    assert IndexConfig(search_backend="jnp").resolved_search_backend() == "jnp"
-    assert IndexConfig().resolved_search_backend() == "pallas"
-    from repro.kernels import ops
-
-    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "native")
-    ops._interpret_default.cache_clear()
-    try:
-        assert IndexConfig(kernel_backend="interpret").resolved_interpret() is True
-        assert IndexConfig(kernel_backend="auto").resolved_interpret() is False
-        assert IndexConfig().resolved_interpret() is None  # defer to env at call
-    finally:
-        ops._interpret_default.cache_clear()
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
+    keys, vals = _corpus(rng, 120)
+    cfg = IndexConfig(delta_capacity=96, auto_merge_threshold=None,
+                      scan_window=5, search_backend="jnp")
+    index = StringIndex.bulk_load(keys, vals, cfg)
+    assert index.config is cfg and index.ti.de_off.shape[0] == 96
+    res = index.execute([ScanRequest(keys[0]), GetRequest(keys[1])])
+    assert [k for k, _ in res.results[0].entries] == keys[:5]
+    assert res.results[1].value == int(vals[1])
     with pytest.raises(ValueError):
-        IndexConfig(kernel_backend="bogus").resolved_interpret()
-    with pytest.raises(ValueError):
-        IndexConfig(search_backend="bogus").resolved_search_backend()
+        IndexConfig(search_backend="bogus")
 
 
 def test_scan_window_grouping_and_default(rng):
@@ -268,15 +265,14 @@ def test_scan_window_grouping_and_default(rng):
     assert [k for k, _ in res.results[2].entries] == keys[3:11]
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-def test_delete_tombstone_semantics(rng, backend):
+@pytest.mark.parametrize("form", ["built", "loaded"])
+def test_delete_tombstone_semantics(rng, form, as_form):
     """DELETE completes the typed op family (DESIGN.md §9): delta-buffer
     tombstones shadow the frozen base immediately for gets, reconcile
     physically at merge_delta, and puts resurrect."""
     keys, vals = _corpus(rng, 300)
-    cfg = IndexConfig(delta_capacity=256, auto_merge_threshold=None,
-                      search_backend=backend)
-    index = StringIndex.bulk_load(keys, vals, cfg)
+    cfg = IndexConfig(delta_capacity=256, auto_merge_threshold=None)
+    index = as_form(StringIndex.bulk_load(keys, vals, cfg), form)
     res = index.execute([
         DeleteRequest(keys[3]),          # base-resident -> tombstone shadow
         DeleteRequest(b"never-existed"),  # absent -> NOT_FOUND
