@@ -227,24 +227,40 @@ def freeze(
     )
 
 
-def pad_queries(keys, width: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Host helper: list[bytes] -> zero-padded (B, width) uint8 + true lens.
+def pack_queries(keys, width: int) -> np.ndarray:
+    """Host helper: list[bytes] -> ONE (B, width + 4) uint8 buffer, a get
+    group's whole device input: each row is the key's first ``width``
+    bytes, zero-padded, then its length clipped to ``width + 1`` as 4
+    little-endian bytes (:func:`unpack_queries` splits it on the device).
 
-    Lengths are clipped to ``width + 1``: the ``width + 1`` value is an
-    over-width SENTINEL, not a length.  No stored key can have it (the host
-    builder rejects over-width keys and :func:`insert_batch` refuses them),
-    so ``_str_eq``'s length comparison makes an over-width query miss every
-    stored key — device search degrades to a clean not-found instead of
-    matching a truncated alias.
+    The ``width + 1`` length is an over-width SENTINEL, not a length.  No
+    stored key can have it (the host builder rejects over-width keys and
+    :func:`insert_batch` refuses them), so ``_str_eq``'s length comparison
+    makes an over-width query miss every stored key — device search
+    degrades to a clean not-found instead of matching a truncated alias.
     """
-    B = len(keys)
-    qb = np.zeros((B, width), np.uint8)
-    ql = np.zeros(B, np.int32)
-    for i, k in enumerate(keys):
-        kb = np.frombuffer(k[:width], np.uint8)
-        qb[i, : kb.shape[0]] = kb
-        ql[i] = min(len(k), width + 1)
-    return qb, ql
+    limit = width + 1
+    rows = b"".join([k[:width].ljust(width, b"\0")
+                     + min(len(k), limit).to_bytes(4, "little")
+                     for k in keys])
+    return np.frombuffer(bytearray(rows), np.uint8).reshape(
+        len(keys), width + 4)
+
+
+def pad_queries(keys, width: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Host helper: list[bytes] -> zero-padded (B, width) uint8 + lengths
+    clipped to the ``width + 1`` sentinel (see :func:`pack_queries`)."""
+    buf = pack_queries(keys, width)
+    lens = np.ascontiguousarray(buf[:, width:]).view("<i4").reshape(-1)
+    return np.ascontiguousarray(buf[:, :width]), lens.astype(np.int32)
+
+
+def unpack_queries(packed: jax.Array, width: int):
+    """Device side of :func:`pack_queries`: ``(qbytes, qlens)``, the
+    (B, width) uint8 key bytes and the int32 lengths."""
+    lb = packed[:, width:].astype(jnp.int32)
+    qlens = lb[:, 0] | lb[:, 1] << 8 | lb[:, 2] << 16 | lb[:, 3] << 24
+    return packed[:, :width], qlens
 
 
 def _tag(item: jax.Array) -> jax.Array:
@@ -333,10 +349,15 @@ def base_search(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array):
     return base_search_impl(ti, qbytes, qlens)[:2]
 
 
-@jax.jit
-def _search_batch_jit(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array):
-    """:func:`search_batch` plus the search's three counters:
-    (found, eid, is_delta, iters, model_iters, rounds)."""
+def search_impl(ti: TensorIndex, qbytes, qlens):
+    """Point lookup with the delta probe, traceable: ``(found, eid,
+    is_delta, iters, model_iters, rounds)``, the counters those of
+    :func:`base_search_impl`.
+
+    The delta-buffer probe runs ahead of the base walk.  Tombstoned delta
+    entries (see :func:`delete_batch`) shadow their base key: such queries
+    report not-found.
+    """
     dfound, did = _delta_lookup(ti, qbytes, qlens)
     # a tombstoned delta entry SHADOWS the base: the key is absent until a
     # put resurrects it or merge_delta reconciles the delete (DESIGN.md §9)
@@ -348,14 +369,10 @@ def _search_batch_jit(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array):
     return found, eid, dfound & ~dtomb, iters, model_iters, rounds
 
 
+@jax.jit
 def search_batch(ti: TensorIndex, qbytes: jax.Array, qlens: jax.Array):
-    """Batched point lookup. Returns (found, eid, is_delta).
-
-    The delta-buffer probe runs ahead of the base walk.  Tombstoned delta
-    entries (see :func:`delete_batch`) shadow their base key: such queries
-    report not-found.
-    """
-    return _search_batch_jit(ti, qbytes, qlens)[:3]
+    """Batched point lookup (:func:`search_impl`): (found, eid, is_delta)."""
+    return search_impl(ti, qbytes, qlens)[:3]
 
 
 @jax.jit
@@ -369,6 +386,20 @@ def lookup_values(ti: TensorIndex, eid: jax.Array, is_delta: jax.Array):
         jnp.where(is_delta, d_lo, base_lo),
         jnp.where(is_delta, d_hi, base_hi),
     )
+
+
+@jax.jit
+def _search_batch_jit(ti: TensorIndex, packed: jax.Array):
+    """A get group's one program: :func:`pack_queries` rows in, ONE
+    (B + 1, 3) int32 array out — rows ``(found, value lo, value hi)`` per
+    query, then the counters ``(iters, model_iters, rounds)``."""
+    qbytes, qlens = unpack_queries(packed, ti.width)
+    found, eid, isd, iters, model_iters, rounds = search_impl(
+        ti, qbytes, qlens)
+    lo, hi = lookup_values(ti, eid, isd)        # inlined into this program
+    return jnp.concatenate([
+        jnp.stack([found.astype(jnp.int32), lo, hi], axis=1),
+        jnp.stack([iters, model_iters, rounds]).astype(jnp.int32)[None]])
 
 
 # ---------------------------------------------------------------------------

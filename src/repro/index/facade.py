@@ -52,6 +52,7 @@ from repro.core.tensor_index import (
     insert_batch,
     lookup_values,
     merge_delta,
+    pack_queries,
     pad_queries,
     scan_batch,
     _search_batch_jit,
@@ -267,6 +268,9 @@ class StringIndexBase:
     # terminal-resolve compare rounds over all get groups (one per group
     # unless a 16-bit h-pointer hash collides); None where not counted
     resolve_rounds: Optional[int] = None
+    # device interactions of get groups: program launches plus explicit
+    # host<->device transfers (three per group); None where not counted
+    device_calls: Optional[int] = None
 
     def execute(self, batch: Sequence[Request]) -> BatchResult:
         raise NotImplementedError
@@ -295,6 +299,7 @@ class StringIndex(StringIndexBase):
     walk_iters: int
     model_step_iters: int
     resolve_rounds: int
+    device_calls: int
 
     def __init__(self, builder: Optional[LITSBuilder], ti: TensorIndex,
                  config: IndexConfig):
@@ -304,6 +309,7 @@ class StringIndex(StringIndexBase):
         self.merge_count = 0
         self.host_syncs = 0
         self.walk_iters = self.model_step_iters = self.resolve_rounds = 0
+        self.device_calls = 0
         self._host_pool = None         # lazy (key_bytes, ent_off, ent_len) copies
         # None = no merge in flight; a list = the epoch-merge journal: every
         # mutation applied between begin_merge() and commit_merge() is
@@ -412,23 +418,26 @@ class StringIndex(StringIndexBase):
             return np.zeros(0, bool), np.zeros(0, np.int64)
         import jax
 
+        # three device calls: one transfer in, one program, one copy out
         with TraceAnnotation("lits.index.get.encode"):
-            qb, ql = pad_queries(list(keys), self.ti.width)
-            qb, ql = jnp.asarray(qb), jnp.asarray(ql)
+            packed = jax.device_put(pack_queries(keys, self.ti.width))
+            self.device_calls += 1
         with TraceAnnotation("lits.index.get.dispatch"):
-            found, eid, isd, iters, model_iters, rounds = _search_batch_jit(
-                self.ti, qb, ql)
-            lo, hi = lookup_values(self.ti, eid, isd)
+            out = _search_batch_jit(self.ti, packed)
+            self.device_calls += 1
         # ONE host sync for the whole get group
         with TraceAnnotation("lits.index.get.sync"):
-            found, lo, hi, iters, model_iters, rounds = jax.device_get(
-                (found, lo, hi, iters, model_iters, rounds))
+            out = jax.device_get(out)
+            self.device_calls += 1
         self.host_syncs += 1
-        self.walk_iters += int(iters)
-        self.model_step_iters += int(model_iters)
-        self.resolve_rounds += int(rounds)
+        iters, model_iters, rounds = out[-1].tolist()
+        self.walk_iters += iters
+        self.model_step_iters += model_iters
+        self.resolve_rounds += rounds
         with TraceAnnotation("lits.index.get.decode"):
-            return found, np.where(found, _join_values(lo, hi), 0)
+            found = out[:-1, 0].astype(bool)
+            return found, np.where(found, _join_values(out[:-1, 1],
+                                                       out[:-1, 2]), 0)
 
     def put_batch(self, keys: Sequence[bytes],
                   values: Sequence[int]) -> Tuple[np.ndarray, np.ndarray, bool]:

@@ -87,8 +87,11 @@ def _hlo(lowered):
 
 
 def test_search_and_lookup_compile_for_v5e(chip_index, one_chip):
-    qb, ql = _queries(GETS, chip_index.width, one_chip)
-    assert _hlo(_search_batch_jit.lower(chip_index, qb, ql))
+    """The get group's one program on its packed input
+    (``pack_queries``), and ``lookup_values``, which scans still run."""
+    packed = jax.ShapeDtypeStruct((GETS, chip_index.width + 4), jnp.uint8,
+                                  sharding=one_chip)
+    assert _hlo(_search_batch_jit.lower(chip_index, packed))
     eid = jax.ShapeDtypeStruct((GETS,), jnp.int32, sharding=one_chip)
     isd = jax.ShapeDtypeStruct((GETS,), jnp.bool_, sharding=one_chip)
     assert _hlo(lookup_values.lower(chip_index, eid, isd))

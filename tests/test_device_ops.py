@@ -7,7 +7,8 @@
   float64 CDF, model-node slot positions against the slots the builder
   filled, and the terminal resolve against a dict of cnode contents;
 * the traversal is not selectable by config or environment;
-* device hashes against the host hashes, and over-width keys.
+* device hashes against the host hashes, over-width keys, and the packed
+  query buffer read back on the device.
 """
 import functools
 
@@ -27,7 +28,7 @@ from repro.core.builder import (
 from repro.core.hpt import positions_impl
 from repro.core.strings import key_hash16, random_strings
 from repro.core.strops import hash16, hash32
-from repro.core.tensor_index import _search_batch_jit
+from repro.core.tensor_index import pack_queries, search_impl, unpack_queries
 from repro.core.walk import resolve_terminal, walk_terminal
 from repro.data.synthetic import load
 from repro.index import IndexConfig, StringIndex
@@ -301,7 +302,8 @@ def test_search_matches_host(rng, corpus):
     b, ti = _build_index(keys, vals)
     qb, ql = pad_queries(queries, ti.width)
     qb, ql = jnp.asarray(qb), jnp.asarray(ql)
-    found, eid, isd, iters, model_iters, rounds = _search_batch_jit(ti, qb, ql)
+    found, eid, isd, iters, model_iters, rounds = jax.jit(search_impl)(
+        ti, qb, ql)
     # ground truth: found iff the query is a stored key, with its value
     want = dict(zip(keys, vals.tolist()))
     present = np.array([q in want for q in queries])
@@ -479,6 +481,28 @@ def test_hash_device_host_bit_identical(rng):
     assert (dev == host).all()
     dev32 = np.asarray(hash32(jnp.asarray(ss.bytes), jnp.asarray(lens)))
     assert dev32.dtype == np.uint32 and (dev32 != 0).any()
+
+
+@pytest.mark.parametrize("width", [8, 43, 300])
+def test_packed_queries_round_trip(width):
+    """``pack_queries`` -> ``unpack_queries`` in a program gives the key
+    bytes zero-padded to ``width`` and each length clipped to the
+    ``width + 1`` sentinel: lengths past 255 and zero bytes exactly."""
+    rng = np.random.default_rng(width)
+    lens = list(range(width + 3)) + [width + 300, 70000]
+    keys = [rng.integers(0, 4, n).astype(np.uint8).tobytes() for n in lens]
+    keys += [b"\x00" * width, b"a\x00"]
+    qb, ql = jax.jit(unpack_queries, static_argnums=1)(
+        jnp.asarray(pack_queries(keys, width)), width)
+    want = np.zeros((len(keys), width), np.uint8)
+    for i, k in enumerate(keys):
+        want[i, : min(len(k), width)] = np.frombuffer(k[:width], np.uint8)
+    assert np.asarray(qb).tolist() == want.tolist()
+    want_lens = [min(len(k), width + 1) for k in keys]
+    assert np.asarray(ql).tolist() == want_lens
+    host_qb, host_ql = pad_queries(keys, width)
+    assert host_qb.tolist() == want.tolist()
+    assert host_ql.dtype == np.int32 and host_ql.tolist() == want_lens
 
 
 def test_insert_rejects_overwidth_keys(rng):

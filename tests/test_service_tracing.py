@@ -5,6 +5,10 @@
   on the flusher's thread, the flush span carrying its ``flush`` id and
   ``ops``;
 * ``StringIndex.host_syncs`` counts one device sync per op group;
+* a get group makes three device calls (``StringIndex.device_calls``: one
+  transfer in, one program, one copy out) and never runs
+  ``lookup_values``; ``ServiceStats.device_calls`` mirrors the counter,
+  None on the mesh index;
 * ``ServiceStats.walk_iters`` and ``model_step_iters`` mirror the search
   walk's iteration counts, read in the get group's one sync;
 * ``ServiceStats.resolve_rounds`` mirrors the terminal resolve's compare
@@ -128,6 +132,54 @@ def test_host_syncs_count_one_per_op_group(rng, ops, syncs):
     assert idx.host_syncs - before == syncs
 
 
+def test_get_group_makes_three_device_calls(rng, monkeypatch):
+    import repro.index.facade as facade
+
+    def refuse(*_a, **_kw):
+        raise AssertionError("a get group ran lookup_values")
+
+    monkeypatch.setattr(facade, "lookup_values", refuse)
+    svc, keys = _service(rng)
+    idx = svc.index
+    program, launched = facade._search_batch_jit, []
+
+    def launch(ti, packed):                 # the count as the program starts
+        launched.append(idx.device_calls)
+        return program(ti, packed)
+
+    monkeypatch.setattr(facade, "_search_batch_jit", launch)
+    svc.reset_stats()
+    for batch in ([GetRequest(keys[0])],
+                  [GetRequest(k) for k in keys[:37]] + [GetRequest(b"no")],
+                  [PutRequest(b"fresh", 5), GetRequest(b"fresh")]):
+        calls, syncs = idx.device_calls, idx.host_syncs
+        got = svc.execute(batch)
+        assert all(r.status == Status.OK for r in got[:-1])
+        assert idx.device_calls - calls == 3       # only the get group
+        assert launched[-1] == calls + 1           # the transfer went first
+        assert idx.host_syncs - syncs == len({type(r) for r in batch})
+    s = svc.stats()
+    svc.close()
+    assert s.flushes == 3 and s.device_calls == 9 == idx.device_calls
+    assert len(launched) == 3
+    assert s.host_syncs == 4
+
+
+def test_device_calls_are_none_on_the_mesh(rng):
+    from repro.distributed.index_service import DistributedStringIndex
+
+    keys, vals = _corpus(rng, 60)
+    dsi = DistributedStringIndex.build(
+        [IndexService.encode_key("t", k) for k in keys], vals, n_shards=1)
+    svc = IndexService(dsi, ServiceConfig(default_tenant="t",
+                                          merge_threshold=None))
+    got = svc.execute([GetRequest(keys[0])])
+    s = svc.stats()
+    svc.close()
+    assert got[0].value == int(vals[0])
+    assert dsi.device_calls is None and s.device_calls is None
+
+
 class _HeldIndex(StringIndexBase):
     """Answers every get NOT_FOUND; the first ``execute`` waits until the
     test releases it, holding the flusher."""
@@ -166,7 +218,7 @@ def test_queue_wait_counts_a_held_flusher():
     assert s1.flush_ms_total >= hold_s * 1e3   # the held flush
     assert s1.host_syncs == 0          # an index that does not count them
     assert s1.walk_iters is None and s1.model_step_iters is None
-    assert s1.resolve_rounds is None
+    assert s1.resolve_rounds is None and s1.device_calls is None
 
 
 def test_reset_stats_zeroes_the_new_counters(rng):

@@ -10,10 +10,15 @@ The acceptance contract (ISSUE 2 / DESIGN.md §8):
 * puts past the delta threshold trigger ``merge_delta`` automatically and
   subsequent gets/scans see the merged keys;
 * a ``save``/``load`` roundtrip reproduces bit-identical ``search_batch``
-  results, and version mismatches raise typed errors.
+  results, and version mismatches raise typed errors;
+* ``get_batch``'s one fused program gives what the search walk, then
+  ``lookup_values``, then the host value join give, counters included,
+  over email and url keys with a live delta, updates, tombstones,
+  resurrected puts, zero bytes and over-width keys.
 """
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -23,10 +28,13 @@ from repro.core import (
     search_batch,
 )
 from repro.core.strings import random_strings
+from repro.core.tensor_index import search_impl
+from repro.data.synthetic import load
 from repro.index import (
     DeleteRequest, GetRequest, IndexConfig, PutRequest, ScanRequest,
     SnapshotFormatError, SnapshotVersionError, Status, StringIndex,
 )
+from repro.index.facade import _join_values
 
 
 def _corpus(rng, n=600):
@@ -380,3 +388,90 @@ def test_values_64bit_roundtrip(rng):
     index = StringIndex.bulk_load(keys, vals)
     found, got = index.get_batch(keys[:50])
     assert found.all() and (got == vals[:50]).all()
+
+
+# index width per corpus: the url index is widened past 255 so that its
+# lengths take two bytes of the packed query buffer
+GET_CORPORA = {"email": None, "url": 264}
+
+
+@pytest.fixture(scope="module", params=sorted(GET_CORPORA))
+def mutated_index(request):
+    """``(index, queries, truth)``: a bulk-loaded index after delta puts,
+    base and delta updates, tombstones on base and delta keys and puts that
+    resurrect some of them; queries over all of these, misses, keys with
+    zero bytes and keys at and past the width; ``truth`` the host dict."""
+    corpus = request.param
+    keys = sorted(set(load(corpus, 3000, seed=3)))
+    vals = np.arange(len(keys), dtype=np.int64) * 7 - (1 << 33)
+    index = StringIndex.bulk_load(keys, vals, IndexConfig(
+        width=GET_CORPORA[corpus], delta_capacity=512,
+        auto_merge_threshold=None))
+    w = index.width
+    truth = dict(zip(keys, vals.tolist()))
+    fresh = [b"fresh-%04d" % i for i in range(60)] + [
+        b"z\x00", b"\x00lead", b"\x00", keys[0] + b"\x00", b"x" * w]
+
+    def put(ks, base):
+        vs = [base + i for i in range(len(ks))]
+        ins, upd, _ = index.put_batch(ks, vs)
+        assert (ins | upd).all()
+        truth.update(zip(ks, vs))
+
+    def delete(ks):
+        deleted, _, _ = index.delete_batch(ks)
+        assert deleted.all()
+        for k in ks:
+            del truth[k]
+
+    put(fresh, 1 << 40)
+    put(keys[10:30] + fresh[:10], -(1 << 50))        # base and delta updates
+    delete(keys[30:60] + fresh[10:20])               # base and delta tombstones
+    put(keys[30:40] + fresh[10:15], 1 << 45)         # resurrected
+    queries = (keys[:80] + fresh + [
+        k + b"~miss" for k in keys[80:100]] + [
+        b"", b"\x00\x00", b"x" * (w - 1) + b"y",   # misses, one at the width
+        b"x" * w + b"\x00",                          # past it, a stored prefix
+        keys[0] + b"q" * (w + 1 - len(keys[0])),
+        b"y" * (w + 300)])
+    order = np.random.default_rng(7).permutation(len(queries))
+    return index, [queries[i] for i in order], truth
+
+
+_walk = jax.jit(search_impl)
+
+
+def _search_then_lookup(ti, keys):
+    """A get group as two programs and six copies: the search walk, then
+    ``lookup_values``, then the host value join."""
+    qb, ql = pad_queries(keys, ti.width)
+    found, eid, isd, iters, model_iters, rounds = _walk(
+        ti, jnp.asarray(qb), jnp.asarray(ql))
+    lo, hi = lookup_values(ti, eid, isd)
+    found = np.asarray(found)
+    return (found, np.where(found, _join_values(lo, hi), 0),
+            (int(iters), int(model_iters), int(rounds)))
+
+
+@pytest.mark.parametrize("group", [1, 37, 128])
+def test_get_batch_matches_search_then_lookup(mutated_index, group):
+    index, queries, truth = mutated_index
+    n = -(-len(queries) // group) * group
+    queries = (queries * 2)[:n]
+    for i in range(0, n, group):
+        keys = queries[i: i + group]
+        counters = (index.walk_iters, index.model_step_iters,
+                    index.resolve_rounds)
+        found, vals = index.get_batch(keys)
+        want_found, want_vals, want_counts = _search_then_lookup(index.ti,
+                                                                 keys)
+        assert found.dtype == bool and vals.dtype == np.int64
+        assert found.tolist() == want_found.tolist()
+        assert vals.tolist() == want_vals.tolist()
+        assert tuple(b - a for a, b in zip(counters, (
+            index.walk_iters, index.model_step_iters,
+            index.resolve_rounds))) == want_counts
+        assert found.tolist() == [k in truth and len(k) <= index.width
+                                  for k in keys]
+        assert vals.tolist() == [truth[k] if f else 0
+                                 for k, f in zip(keys, found.tolist())]
